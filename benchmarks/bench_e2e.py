@@ -85,6 +85,7 @@ from repro.core.cp_als import _sweep_streams
 from repro.core.memctrl import CacheEngineConfig, MemoryControllerConfig
 from repro.core.remap import plan_blocks, plan_blocks_reference
 from repro.kernels import ops
+from repro.platform import enable_compile_cache
 
 ROOT = Path(__file__).resolve().parent.parent
 BASELINE_PATH = ROOT / "BENCH_kernel.json"
@@ -161,7 +162,7 @@ def bench_als_iter(presets, results, rank: int, reps: int):
         # Planned Pallas path (interpret mode on CPU — the BlockSpec DMA
         # schedule is the TPU performance model; wall-clock here tracks the
         # grid-step count, not MXU throughput).
-        ws = ops.make_planned_cp_als(st, rank, interpret=True)
+        ws = ops.make_planned_cp_als(st, rank)
         facs = ws.pad_factors(random_factors(key, st.shape, rank))
         idx, val = jnp.asarray(st.indices), jnp.asarray(st.values)
         facs, lam, fit = ws.sweep(facs, idx, val, nxs, first=True)
@@ -173,7 +174,7 @@ def bench_als_iter(presets, results, rank: int, reps: int):
         jax.block_until_ready(fit)
         t_pallas = (time.perf_counter() - t0) / reps
         results.append(result_record("als_iter_pallas", preset, "iter_s", t_pallas, "s"))
-        print(f"  {preset:10s} pallas(interpret) iter={t_pallas:8.3f}s "
+        print(f"  {preset:10s} pallas iter={t_pallas:8.3f}s "
               f"(plans: {ws.plan_bytes()/2**20:.1f} MiB)")
 
         streams = [st.sorted_by(m) for m in range(st.nmodes)]
@@ -207,7 +208,7 @@ def bench_guard_overhead(results, preset: str, rank: int, iters: int):
     f0 = random_factors(jax.random.PRNGKey(0), st.shape, rank)
     idx, val = jnp.asarray(st.indices), jnp.asarray(st.values)
     nxs = _norm_x_sq(st)
-    ws = ops.make_planned_cp_als(st, rank, interpret=True)
+    ws = ops.make_planned_cp_als(st, rank)
     gc = GuardConfig(policy="raise", check_factors_every=1)
     ws.drive(f0, (idx, val, nxs), iters=2)  # compile first + steady sweeps
     ws.drive(f0, (idx, val, nxs), iters=2, guards=gc)  # + the finite check
@@ -263,7 +264,7 @@ def bench_tucker(results, presets, core_rank: int, reps: int):
         nxs = _norm_x_sq(st)
 
         built = []
-        t_plan = _timed(lambda: built.append(make_planned_tucker(st, ranks, interpret=True)))
+        t_plan = _timed(lambda: built.append(make_planned_tucker(st, ranks)))
         ws = built[0]
         facs = ws.pad_factors(init_tucker_factors(key, st.shape, ranks))
         facs, core, fit = ws.sweep(facs, nxs)
@@ -278,7 +279,7 @@ def bench_tucker(results, presets, core_rank: int, reps: int):
             result_record("tucker_plan_build", preset, "plan_s", t_plan, "s"),
             result_record("tucker_hooi_iter", preset, "iter_s", t_iter, "s"),
         ]
-        print(f"  {preset:10s} plan={t_plan:8.3f}s hooi(interpret) iter={t_iter:8.3f}s "
+        print(f"  {preset:10s} plan={t_plan:8.3f}s hooi iter={t_iter:8.3f}s "
               f"(plans: {ws.plan_bytes()/2**20:.1f} MiB, core ranks {ranks})")
 
     # kind-keyed plan cache, ttmc side (mirrors bench_plan_cache)
@@ -314,7 +315,7 @@ def bench_tt(results, presets, bond_rank: int, reps: int):
         nxs = _norm_x_sq(st)
 
         built = []
-        t_plan = _timed(lambda: built.append(make_planned_tt(st, tt_ranks, interpret=True)))
+        t_plan = _timed(lambda: built.append(make_planned_tt(st, tt_ranks)))
         ws = built[0]
         cores = init_tt_cores(key, st.shape, tt_ranks)
         facs = ws.pad_factors([core_to_matrix(c) for c in cores])
@@ -331,7 +332,7 @@ def bench_tt(results, presets, bond_rank: int, reps: int):
             result_record("tt_plan_build", preset, "plan_s", t_plan, "s"),
             result_record("tt_als_iter", preset, "iter_s", t_iter, "s"),
         ]
-        print(f"  {preset:10s} plan={t_plan:8.3f}s tt-als(interpret) iter={t_iter:8.3f}s "
+        print(f"  {preset:10s} plan={t_plan:8.3f}s tt-als iter={t_iter:8.3f}s "
               f"(plans: {ws.plan_bytes()/2**20:.1f} MiB, bond ranks {tt_ranks})")
 
     # kind-keyed plan cache, tt side (mirrors bench_plan_cache)
@@ -354,35 +355,37 @@ def bench_tt(results, presets, bond_rank: int, reps: int):
           f"hits={stats['hits']} misses={stats['misses']} (tt kind)")
 
 
-_SHARDED_BENCH_CODE = """
-import json, time
-import jax, jax.numpy as jnp, numpy as np
-from repro.core.coo import frostt_like, random_factors
-from repro.dist.sharding import stream_imbalance
-from repro.kernels.ops import make_sharded_planned_cp_als
+def _sharded_sweep_record(preset: str, rank: int, devices: int, reps: int) -> dict:
+    """Build the sharded planned CP-ALS workspace over `devices` devices and
+    time its steady-state sweep."""
+    from repro.dist.sharding import stream_imbalance
 
-preset, rank, devices, reps = {preset!r}, {rank}, {devices}, {reps}
-assert jax.device_count() == devices, jax.devices()
-st = frostt_like(preset)
-t0 = time.perf_counter()
-ws = make_sharded_planned_cp_als(st, rank, devices=devices)
-t_build = time.perf_counter() - t0
-facs = ws.pad_factors(random_factors(jax.random.PRNGKey(0), st.shape, rank))
-nxs = jnp.asarray(float(np.sum(st.values.astype(np.float64) ** 2)), jnp.float32)
-facs, lam, fit = ws.sweep(facs, nxs, first=True)
-facs, lam, fit = ws.sweep(facs, nxs, first=False)  # compile steady state
-jax.block_until_ready(fit)
-t0 = time.perf_counter()
-for _ in range(reps):
-    facs, lam, fit = ws.sweep(facs, nxs, first=False)
-jax.block_until_ready(fit)
-print("RESULT " + json.dumps({{
-    "build_s": t_build,
-    "iter_s": (time.perf_counter() - t0) / reps,
-    "imbalance_x": stream_imbalance(ws.stacks[0].shard_nnz),
-    "plan_mib": ws.plan_bytes() / 2**20,
-}}))
-"""
+    st = frostt_like(preset)
+    t0 = time.perf_counter()
+    ws = ops.make_sharded_planned_cp_als(st, rank, devices=devices)
+    t_build = time.perf_counter() - t0
+    facs = ws.pad_factors(random_factors(jax.random.PRNGKey(0), st.shape, rank))
+    nxs = jnp.asarray(float(np.sum(st.values.astype(np.float64) ** 2)), jnp.float32)
+    facs, lam, fit = ws.sweep(facs, nxs, first=True)
+    facs, lam, fit = ws.sweep(facs, nxs, first=False)  # compile steady state
+    jax.block_until_ready(fit)
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        facs, lam, fit = ws.sweep(facs, nxs, first=False)
+    jax.block_until_ready(fit)
+    return {
+        "build_s": t_build,
+        "iter_s": (time.perf_counter() - t0) / reps,
+        "imbalance_x": stream_imbalance(ws.stacks[0].shard_nnz),
+        "plan_mib": ws.plan_bytes() / 2**20,
+    }
+
+
+_SHARDED_CHILD = (
+    "import json; from benchmarks.bench_e2e import _sharded_sweep_record; "
+    "print('RESULT ' + json.dumps(_sharded_sweep_record({preset!r}, {rank}, "
+    "{devices}, {reps})))"
+)
 
 
 def _steady_sweep_s(step, reps: int) -> float:
@@ -416,7 +419,7 @@ def bench_pms_accuracy(results, presets, rank: int, core_rank: int,
         cfg = PMS_MEDIUM_CFG if preset == "medium" else None
         local_reps = 1 if preset == "medium" else reps
 
-        ws = ops.make_planned_cp_als(st, rank, cfg=cfg, interpret=True)
+        ws = ops.make_planned_cp_als(st, rank, cfg=cfg)
         state = {"f": ws.pad_factors(random_factors(key, st.shape, rank))}
 
         def step_cp():
@@ -429,7 +432,7 @@ def bench_pms_accuracy(results, presets, rank: int, core_rank: int,
         ))
 
         ranks = (core_rank,) * st.nmodes
-        ws = make_planned_tucker(st, ranks, cfg=cfg, interpret=True)
+        ws = make_planned_tucker(st, ranks, cfg=cfg)
         state = {"f": ws.pad_factors(init_tucker_factors(key, st.shape, ranks))}
 
         def step_tk():
@@ -442,7 +445,7 @@ def bench_pms_accuracy(results, presets, rank: int, core_rank: int,
         ))
 
         tt_ranks = (bond_rank,) * (st.nmodes - 1)
-        ws = make_planned_tt(st, tt_ranks, cfg=cfg, interpret=True)
+        ws = make_planned_tt(st, tt_ranks, cfg=cfg)
         cores = init_tt_cores(key, st.shape, tt_ranks)
         state = {"f": ws.pad_factors([core_to_matrix(c) for c in cores])}
 
@@ -477,7 +480,7 @@ def bench_pms_calibration(results, preset: str, rank: int, reps: int):
     st = frostt_like(preset)
     nxs = _norm_x_sq(st)
     idx, val = jnp.asarray(st.indices), jnp.asarray(st.values)
-    ws = ops.make_planned_cp_als(st, rank, interpret=True)
+    ws = ops.make_planned_cp_als(st, rank)
     state = {"f": ws.pad_factors(random_factors(jax.random.PRNGKey(0), st.shape, rank))}
 
     def step():
@@ -508,27 +511,39 @@ def bench_pms_calibration(results, preset: str, rank: int, reps: int):
 
 
 def bench_sharded(results, presets, rank: int, devices: int, reps: int):
-    """Distributed planned CP-ALS on a forced multi-device host platform:
-    subprocess-spawned (the device count locks at first jax init), reporting
-    workspace build, steady-state shard_map sweep, and partition balance."""
-    print(f"== sharded planned path ({devices} forced host devices, subprocess)")
+    """Distributed planned CP-ALS: workspace build, steady-state shard_map
+    sweep, and partition balance.  Runs in this process when it already
+    sees `devices` devices.  Otherwise, on the CPU only, a child process
+    forces that many host devices (the count locks when JAX starts); a
+    child could not reach an accelerator this process holds."""
+    in_process = jax.device_count() >= devices
+    if not in_process and jax.default_backend() != "cpu":
+        print(f"== sharded planned path skipped: {jax.device_count()} "
+              f"{jax.default_backend()} device(s), {devices} needed")
+        return
+    print(f"== sharded planned path ({devices} devices, "
+          f"{'in process' if in_process else 'forced host devices, subprocess'})")
     for preset in presets:
-        code = _SHARDED_BENCH_CODE.format(
-            preset=preset, rank=rank, devices=devices, reps=reps
-        )
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
-        env["PYTHONPATH"] = str(ROOT / "src")
-        out = subprocess.run(
-            [sys.executable, "-c", code], env=env, capture_output=True,
-            text=True, timeout=900, cwd=ROOT,
-        )
-        if out.returncode != 0:
-            raise RuntimeError(
-                f"sharded bench subprocess failed:\n{out.stdout}\n{out.stderr[-3000:]}"
+        if in_process:
+            r = _sharded_sweep_record(preset, rank, devices, reps)
+        else:
+            env = dict(os.environ)
+            env["JAX_PLATFORMS"] = "cpu"
+            env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+            env["PYTHONPATH"] = os.pathsep.join((str(ROOT / "src"), str(ROOT)))
+            code = _SHARDED_CHILD.format(
+                preset=preset, rank=rank, devices=devices, reps=reps
             )
-        line = [l for l in out.stdout.splitlines() if l.startswith("RESULT ")][-1]
-        r = json.loads(line[len("RESULT "):])
+            out = subprocess.run(
+                [sys.executable, "-c", code], env=env, capture_output=True,
+                text=True, timeout=900, cwd=ROOT,
+            )
+            if out.returncode != 0:
+                raise RuntimeError(
+                    f"sharded bench subprocess failed:\n{out.stdout}\n{out.stderr[-3000:]}"
+                )
+            line = [l for l in out.stdout.splitlines() if l.startswith("RESULT ")][-1]
+            r = json.loads(line[len("RESULT "):])
         results += [
             result_record("sharded_plan_build", preset, "build_s", r["build_s"], "s"),
             result_record("sharded_als_iter", preset, "iter_s", r["iter_s"], "s"),
@@ -542,6 +557,7 @@ def bench_sharded(results, presets, rank: int, devices: int, reps: int):
 
 def main(fast: bool = False, out: str | None = None) -> dict:
     path = _resolve_out(out, fast)
+    enable_compile_cache(ROOT)
     plan_presets = ("small", "4d_small", "5d_small") if fast else (
         "small", "medium", "4d_small", "5d_small")
     als_presets = ("small", "4d_small", "5d_small")

@@ -64,12 +64,13 @@ def run_cp(st, fast: bool, devices: int, trace=None, auto_tune=False):
     # PMS (Sec 5.3): pick the memory-controller configuration for MTTKRP
     _print_pms(search(st, 0, rank, top_k=3))
 
-    # CP-ALS entirely on the planned Pallas kernel (interpret mode on CPU):
+    # CP-ALS entirely on the planned Pallas kernel (compiled on a TPU,
+    # interpreted on the CPU):
     # plans are built once per mode and amortized over all iterations.
     small = frostt_like("tiny")
     # With --auto-tune the facade builds (or, for "cached", loads) each
     # mode's PMS-selected configuration itself — no prebuilt workspace.
-    planned = None if auto_tune else make_planned_cp_als(small, 8, interpret=True)
+    planned = None if auto_tune else make_planned_cp_als(small, 8)
     if planned is not None:
         print(f"planned workspace: {small.nmodes} mode plans, "
               f"{planned.plan_bytes()/2**20:.2f} MiB of remapped copies on HBM")
@@ -79,7 +80,7 @@ def run_cp(st, fast: bool, devices: int, trace=None, auto_tune=False):
     state = decompose(small, 8, format="cp", iters=iters, planned=planned,
                       auto_tune=auto_tune, verbose=True, trace=trace)
     print(f"CP-ALS fit={state.fit_history[-1]:.4f} in {time.time()-t0:.1f}s "
-          f"(PlannedCPALS, interpret mode)")
+          f"(PlannedCPALS)")
 
     if devices > 1:
         # The same loop distributed: per-mode balanced stream partitions,
@@ -114,7 +115,7 @@ def run_tucker(st, fast: bool, devices: int, trace=None, auto_tune=False):
     # MTTKRP uses, built once per mode and amortized over all iterations.
     small = frostt_like("tiny")
     ranks_small = (4, 4, 4)
-    planned = None if auto_tune else make_planned_tucker(small, ranks_small, interpret=True)
+    planned = None if auto_tune else make_planned_tucker(small, ranks_small)
     if planned is not None:
         print(f"planned workspace: {small.nmodes} mode plans, "
               f"{planned.plan_bytes()/2**20:.2f} MiB of remapped copies on HBM")
@@ -125,7 +126,7 @@ def run_tucker(st, fast: bool, devices: int, trace=None, auto_tune=False):
                       planned=planned, auto_tune=auto_tune, verbose=True,
                       trace=trace)
     print(f"Tucker HOOI fit={state.fit_history[-1]:.4f} core={state.core.shape} "
-          f"in {time.time()-t0:.1f}s (PlannedTucker, interpret mode)")
+          f"in {time.time()-t0:.1f}s (PlannedTucker)")
 
     if devices > 1:
         t0 = time.time()
@@ -158,7 +159,7 @@ def run_tt(st, fast: bool, devices: int, trace=None, auto_tune=False):
     # iterations.
     small = frostt_like("tiny")
     ranks_small = (4, 4)
-    planned = None if auto_tune else make_planned_tt(small, ranks_small, interpret=True)
+    planned = None if auto_tune else make_planned_tt(small, ranks_small)
     if planned is not None:
         print(f"planned workspace: {small.nmodes} mode plans, "
               f"{planned.plan_bytes()/2**20:.2f} MiB of remapped copies on HBM")
@@ -169,7 +170,7 @@ def run_tt(st, fast: bool, devices: int, trace=None, auto_tune=False):
                       planned=planned, auto_tune=auto_tune, verbose=True,
                       trace=trace)
     print(f"TT-ALS fit={state.fit_history[-1]:.4f} tt_ranks={state.tt_ranks} "
-          f"in {time.time()-t0:.1f}s (PlannedTT, interpret mode)")
+          f"in {time.time()-t0:.1f}s (PlannedTT)")
 
     if devices > 1:
         t0 = time.time()
@@ -188,9 +189,14 @@ def run_tt(st, fast: bool, devices: int, trace=None, auto_tune=False):
 
 def main(fast: bool = False, algo: str = "cp", devices: int = 1,
          trace: str | None = None, auto_tune=False):
+    from pathlib import Path
+
     import jax
 
     from repro.core.coo import frostt_like
+    from repro.platform import enable_compile_cache
+
+    enable_compile_cache(Path(__file__).resolve().parent.parent)
 
     if devices > 1 and jax.device_count() < devices:
         raise SystemExit(

@@ -27,7 +27,8 @@ import os
 import sys
 from pathlib import Path
 
-sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
+ROOT = Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(ROOT / "src"))
 
 
 def main(argv=None) -> int:
@@ -42,6 +43,7 @@ def main(argv=None) -> int:
     if a.cache_dir:
         os.environ["REPRO_AUTOTUNE_DIR"] = a.cache_dir
 
+    from repro.platform import enable_compile_cache
     from repro.tune import (
         calibrate,
         calibrate_and_store,
@@ -51,6 +53,7 @@ def main(argv=None) -> int:
         resolve_spec,
     )
 
+    enable_compile_cache(ROOT)
     kwargs = dict(preset=a.preset, rank=a.rank, reps=a.reps)
     if a.dry_run:
         result = calibrate(**kwargs)

@@ -65,8 +65,8 @@ def _lane_ranks(format: str, r, nmodes: int) -> tuple[int, ...]:
     return tuple(bounds[m] * bounds[m + 1] for m in range(nmodes))
 
 
-def _admitted(st, r, *, format, method, planned, hbm_budget, interpret,
-              auto_tune, cfg, verbose):
+def _admitted(st, r, *, format, method, planned, hbm_budget, auto_tune, cfg,
+              verbose):
     """`hbm_budget=` handling: admit a prebuilt workspace as-is, or run the
     graceful-degradation ladder (`repro.resilience.plan_with_budget`) over
     freshly built workspaces — stepping down the DMA block size, then the
@@ -102,7 +102,7 @@ def _admitted(st, r, *, format, method, planned, hbm_budget, interpret,
     else:
         from .tt.als import make_planned_tt as build_ws
     ws, decision = plan_with_budget(
-        lambda c: build_ws(st, r, cfg=c, interpret=interpret),
+        lambda c: build_ws(st, r, cfg=c),
         hbm_budget, cfg=cfg, reference_bytes=ref_bytes,
     )
     if verbose:
@@ -126,7 +126,6 @@ def decompose(
     seed: int = 0,
     tol: float | None = None,
     planned=None,
-    interpret: bool = True,
     auto_tune: bool | str = False,
     spec="default",
     cfg=None,
@@ -161,8 +160,9 @@ def decompose(
       planned: a prebuilt format workspace (`PlannedCPALS`, `PlannedTucker`,
         `PlannedTT`, or their Sharded* variants) to reuse plans across
         calls; type-checked against `format`/`method`.
-      interpret / auto_tune / cfg: pallas-path knobs — interpret-mode Pallas
-        (CPU containers), per-mode PMS tuning, explicit controller config.
+      auto_tune / cfg: pallas-path knobs — per-mode PMS tuning, explicit
+        controller config.  The kernels run compiled on a TPU and in
+        interpret mode elsewhere; the platform decides (`repro.platform`).
         auto_tune accepts False, True, or "cached": "cached" serves each
         mode's persisted PMS winner from the on-disk autotune cache
         (repro.tune.cache; `$REPRO_AUTOTUNE_DIR`), skipping the config
@@ -219,12 +219,12 @@ def decompose(
         if hbm_budget is not None:
             planned, method = _admitted(
                 st, r, format=format, method=method, planned=planned,
-                hbm_budget=hbm_budget, interpret=interpret,
-                auto_tune=auto_tune, cfg=cfg, verbose=verbose,
+                hbm_budget=hbm_budget, auto_tune=auto_tune, cfg=cfg,
+                verbose=verbose,
             )
         common = dict(
             iters=iters, method=method, seed=seed, tol=tol, planned=planned,
-            interpret=interpret, auto_tune=auto_tune, spec=spec, cfg=cfg,
+            auto_tune=auto_tune, spec=spec, cfg=cfg,
             jit_sweep=jit_sweep, devices=devices, dist=dist, verbose=verbose,
             guards=guards, checkpoint_every=checkpoint_every,
             checkpoint_path=checkpoint_path,

@@ -29,6 +29,7 @@ from .loop import (
     check_drive_extras,
     check_planned_method,
     check_workspace,
+    f32_matmuls,
     finish_iter,
     require_sharded_sweep,
 )
@@ -157,6 +158,7 @@ def _sweep_remap(factors, idx, val, norm_x_sq, *, shape, method, first):
     return tuple(factors), lam, idx, val, fit
 
 
+@f32_matmuls
 def cp_als(
     st: SparseTensor,
     rank: int,
@@ -168,7 +170,6 @@ def cp_als(
     tol: float | None = None,
     mttkrp_fn: Callable | None = None,
     planned=None,
-    interpret: bool = True,
     auto_tune: bool | str = False,
     spec="default",
     cfg=None,
@@ -199,7 +200,7 @@ def cp_als(
     mttkrp_fn: optional override with signature (indices, values, factors,
                mode, out_rows) -> (I_mode, R).  Forces the eager loop (the
                override may not be jit-traceable).
-    planned / interpret / auto_tune / cfg: pallas-path knobs — pass a
+    planned / auto_tune / cfg: pallas-path knobs — pass a
                prebuilt `PlannedCPALS` (or `ShardedPlannedCPALS` for
                'pallas_sharded') to reuse plans across calls, or let
                auto_tune run the PMS per mode (Sec. 5.3; worst-shard
@@ -245,7 +246,7 @@ def cp_als(
         if planned is None:
             planned = make_sharded_planned_cp_als(
                 st, rank, dist=dist, devices=devices, cfg=cfg,
-                auto_tune=auto_tune, spec=spec, interpret=interpret,
+                auto_tune=auto_tune, spec=spec,
             )
         else:
             check_workspace(
@@ -265,7 +266,6 @@ def cp_als(
         if planned is None:
             planned = make_planned_cp_als(
                 st, rank, cfg=cfg, auto_tune=auto_tune, spec=spec,
-                interpret=interpret,
             )
         else:
             check_workspace(

@@ -20,13 +20,17 @@ consumed by `PlannedWorkspace.drive` and re-exported from `repro.resilience`.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import warnings
+
+import jax
 
 from ..obs import metrics as _metrics
 from ..obs import trace as _trace
 
 __all__ = [
+    "f32_matmuls",
     "finish_iter",
     "check_planned_method",
     "check_drive_extras",
@@ -38,6 +42,21 @@ __all__ = [
 ]
 
 GUARD_POLICIES = ("raise", "fallback", "restart")
+
+
+def f32_matmuls(driver):
+    """Run a format driver with every matmul it traces at full f32
+    precision.  At default precision a TPU rounds f32 matmul operands to
+    bf16 (8 mantissa bits) for the grams, solves and fits of the normal
+    equations; the CPU always multiplies in f32, so this changes nothing
+    there.  (The kernels set the precision of their own dots.)"""
+
+    @functools.wraps(driver)
+    def run(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return driver(*args, **kwargs)
+
+    return run
 
 #: A fit must drop this far below the best seen before an iteration counts
 #: toward the divergence patience — plain convergence noise stays inert.

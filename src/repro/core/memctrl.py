@@ -28,6 +28,7 @@ __all__ = [
     "RemapperConfig",
     "MemoryControllerConfig",
     "TPUSpec",
+    "TPU_SPECS",
     "spec_to_dict",
     "spec_from_dict",
     "config_to_dict",
@@ -65,7 +66,14 @@ class RemapperConfig:
 
 @dataclasses.dataclass(frozen=True)
 class TPUSpec:
-    """Target-hardware constants (TPU v5e)."""
+    """Hardware constants of one chip.  The defaults are the TPU v5e.
+
+    Published (Google Cloud documentation, "TPU v5e"): 197 TFLOP/s bf16,
+    16 GB of HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect
+    (4 links of 50 GB/s).  Not published: `peak_flops_f32` is an assumption
+    (half the bf16 peak); the VMEM and SMEM capacities are the ones the
+    compiler reports for "TPU v5 lite"
+    (`jax.experimental.pallas.tpu.get_tpu_info()`)."""
 
     peak_flops: float = 197e12  # bf16
     peak_flops_f32: float = 98.5e12
@@ -75,6 +83,13 @@ class TPUSpec:
     ici_bw_per_link: float = 50e9  # bytes/s/link
     ici_links: int = 4  # 2D torus on v5e: 4 links/chip
     hbm_bytes: int = 16 * 1024**3
+    smem_bytes: int = 1024 * 1024
+
+
+# Hardware constants by `jax.Device.device_kind` (see `repro.platform`).
+TPU_SPECS: dict[str, TPUSpec] = {
+    "TPU v5 lite": TPUSpec(),
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,26 +98,38 @@ class MemoryControllerConfig:
     dma: DMAEngineConfig = DMAEngineConfig()
     remapper: RemapperConfig = RemapperConfig()
 
-    def vmem_bytes(self, rank_padded: int, n_in: int = 2) -> int:
-        """VMEM footprint of one kernel instance (per buffer set): the output
-        accumulator tile + n_in (= N-1) resident input factor tiles + the
-        non-zero block stream (vals + N local index vectors).  Element widths
-        come from the Remapper configuration, not hardcoded 4-byte literals.
-        Pallas double-buffers streamed operands -> multiply by dma.buffers."""
+    def _vmem(self, out_cols: int, in_widths: tuple[int, ...], scratch_cols: int) -> int:
+        """VMEM of one kernel instance (kernels/blocked.py), shared by the
+        three kernel models.
+
+        Double-buffered (x dma.buffers): the accumulator tile twice (carried
+        in and written out, `out_cols` lanes), one resident factor tile per
+        input mode at its own lane width, and the stream blocks — one
+        (1, blk) row per stream, which VMEM pads to 8 sublanes.  Single
+        copies: the one-hot gather (widest input tile x blk), the one-hot
+        segment matrix (tile_i x blk), and `scratch_cols` lanes of (blk, .)
+        per-element intermediates.  Element widths come from the Remapper
+        configuration."""
         c, d, r = self.cache, self.dma, self.remapper
+        in_tiles = c.input_tiles(len(in_widths))
         tiles = (
-            (c.tile_i + sum(c.input_tiles(n_in)) * c.resident_tiles)
-            * rank_padded
-            * r.value_bytes
-        )
-        stream = d.blk * (r.value_bytes + (n_in + 1) * r.index_bytes)
-        return d.buffers * (tiles + stream)
+            2 * c.tile_i * out_cols
+            + sum(t * w for t, w in zip(in_tiles, in_widths)) * c.resident_tiles
+        ) * r.value_bytes
+        stream = 8 * d.blk * (r.value_bytes + (len(in_widths) + 1) * r.index_bytes)
+        onehots = d.blk * (max(in_tiles) + c.tile_i) * r.value_bytes
+        return d.buffers * (tiles + stream) + onehots + d.blk * scratch_cols * r.value_bytes
+
+    def vmem_bytes(self, rank_padded: int, n_in: int = 2) -> int:
+        """VMEM footprint of one MTTKRP kernel instance: R_pad-wide tiles and
+        the gathered rows plus their running Hadamard product as scratch."""
+        return self._vmem(rank_padded, (rank_padded,) * n_in, 2 * rank_padded)
 
     def fits(self, spec: TPUSpec, rank_padded: int, n_in: int = 2) -> bool:
         return self.vmem_bytes(rank_padded, n_in) <= spec.vmem_bytes * spec.vmem_usable_frac
 
     def vmem_bytes_ttmc(self, out_cols_padded: int, in_rank_pads: tuple[int, ...]) -> int:
-        """VMEM footprint of one TTM-chain kernel instance (per buffer set).
+        """VMEM footprint of one TTM-chain kernel instance.
 
         Differs from the MTTKRP model in the tile widths: the output
         accumulator is a *core-tensor slice* of out_cols_padded =
@@ -110,16 +137,12 @@ class MemoryControllerConfig:
         accumulator multiplicatively in the ranks, which is exactly why the
         TTMc search needs its own fit constraint — and each resident input
         factor tile carries its own lane padding rank_padded(R_m) instead of
-        a shared R_pad.  Stream cost is identical (same BlockPlan layout)."""
-        c, d, r = self.cache, self.dma, self.remapper
-        n_in = len(in_rank_pads)
-        tiles = (
-            c.tile_i * out_cols_padded
-            + sum(t * rp for t, rp in zip(c.input_tiles(n_in), in_rank_pads))
-            * c.resident_tiles
-        ) * r.value_bytes
-        stream = d.blk * (r.value_bytes + (n_in + 1) * r.index_bytes)
-        return d.buffers * (tiles + stream)
+        a shared R_pad.  Scratch: the widest gathered rows plus the spread
+        and the running Kronecker product at the output width."""
+        return self._vmem(
+            out_cols_padded, tuple(in_rank_pads),
+            max(in_rank_pads) + 2 * out_cols_padded,
+        )
 
     def fits_ttmc(self, spec: TPUSpec, out_cols_padded: int, in_rank_pads: tuple[int, ...]) -> bool:
         return (
@@ -133,26 +156,19 @@ class MemoryControllerConfig:
         in_rank_pads: tuple[int, ...],
         iface_cols: int,
     ) -> int:
-        """VMEM footprint of one TT-core kernel instance (per buffer set).
+        """VMEM footprint of one TT-core kernel instance.
 
         Same tile/stream structure as the TTMc model — the output accumulator
         carries out_cols_padded = rank_padded(rl_m*rr_m) lanes and each
         resident core-interface tile its own rank_padded(rl_k*rr_k) — plus
         the two-interface scratch: the left and right chain vectors live at
         (blk, iface_cols) where iface_cols bounds the widest left- and
-        right-chain intermediates.  The chains are recomputed per block in
-        registers/VMEM scratch, not double-buffered (they are not streamed
-        operands), so the scratch term sits outside the buffers multiplier."""
-        c, d, r = self.cache, self.dma, self.remapper
-        n_in = len(in_rank_pads)
-        tiles = (
-            c.tile_i * out_cols_padded
-            + sum(t * rp for t, rp in zip(c.input_tiles(n_in), in_rank_pads))
-            * c.resident_tiles
-        ) * r.value_bytes
-        stream = d.blk * (r.value_bytes + (n_in + 1) * r.index_bytes)
-        scratch = d.blk * iface_cols * r.value_bytes
-        return d.buffers * (tiles + stream) + scratch
+        right-chain intermediates, next to the widest gathered rows and
+        their spread product."""
+        return self._vmem(
+            out_cols_padded, tuple(in_rank_pads),
+            iface_cols + 2 * max(max(in_rank_pads), out_cols_padded),
+        )
 
     def fits_tt(
         self,

@@ -120,7 +120,6 @@ def mttkrp_sharded(
     st=None,
     rank: int | None = None,
     cfg=None,
-    interpret: bool = True,
 ):
     """Build a shard_map'd MTTKRP from a ``ShardingPlan``: the non-zero
     stream is sharded over the plan's data axes (``plan.stream()``), factor
@@ -143,8 +142,6 @@ def mttkrp_sharded(
     The returned callable keeps the (indices, values, factors) signature for
     drop-in use, but the stream arguments are ignored — each shard's
     remapped copy already lives on its device."""
-    from jax.experimental.shard_map import shard_map
-
     if method == "pallas":
         if st is None or rank is None:
             raise ValueError(
@@ -154,9 +151,7 @@ def mttkrp_sharded(
             )
         from ..kernels.ops import make_sharded_planned_mttkrp
 
-        op = make_sharded_planned_mttkrp(
-            st, mode, rank, dist=plan, cfg=cfg, interpret=interpret
-        )
+        op = make_sharded_planned_mttkrp(st, mode, rank, dist=plan, cfg=cfg)
 
         def call_planned(indices, values, factors):
             del indices, values  # per-shard layouts are device-resident
@@ -177,12 +172,12 @@ def mttkrp_sharded(
         in_specs = (plan.stream(), plan.stream()) + tuple(
             P(None, None) for _ in factors
         )
-        return shard_map(
+        return jax.shard_map(
             local_fn,
             mesh=plan.mesh,
             in_specs=in_specs,
             out_specs=P(None, None),
-            check_rep=False,
+            check_vma=False,
         )(indices, values, *factors)
 
     return call

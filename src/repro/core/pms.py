@@ -78,9 +78,10 @@ def _rank_padded(rank: int) -> int:
 
 def resolve_spec(spec) -> TPUSpec:
     """Resolve the `spec=` argument of the search entry points: a `TPUSpec`
-    passes through, ``"default"`` is the datasheet `TPUSpec()`, and
-    ``"measured"`` is this backend's calibrated spec from the autotune cache
-    (`repro.tune`), auto-calibrating on a cache miss."""
+    passes through, ``"default"`` is the published constants of the chip
+    this runs on (`repro.platform.device_spec`), and ``"measured"`` is this
+    backend's calibrated spec from the autotune cache (`repro.tune`),
+    auto-calibrating on a cache miss."""
     if isinstance(spec, TPUSpec):
         return spec
     from ..tune import resolve_spec as _tune_resolve  # deferred: tune -> pms
@@ -316,25 +317,22 @@ def _tt_pairs(
     return in_pairs, pairs[mode]
 
 
-def _tt_iface_cols(in_pairs: tuple[tuple[int, int], ...], n_left: int) -> int:
-    """Widest live columns of the two interface-chain scratch vectors: the
-    left chain's intermediates are (blk, rr_k) wide, the right chain's
-    (blk, rl_k); both start at width 1."""
-    left = max([1] + [p[1] for p in in_pairs[:n_left]])
-    right = max([1] + [p[0] for p in in_pairs[n_left:]])
-    return left + right
+def _tt_iface_cols(in_pairs: tuple[tuple[int, int], ...]) -> int:
+    """Live columns of the two interface-chain scratch vectors: the kernel
+    keeps the left and the right chain each lane-padded to the widest bond
+    (kernels/tt_pallas.py)."""
+    return 2 * _rank_padded(max(max(p) for p in in_pairs))
 
 
 def _tt_vmem(
     cfg: MemoryControllerConfig,
     in_pairs: tuple[tuple[int, int], ...],
     out_pair: tuple[int, int],
-    n_left: int,
 ) -> int:
     return cfg.vmem_bytes_tt(
         _rank_padded(out_pair[0] * out_pair[1]),
         tuple(_rank_padded(a * b) for a, b in in_pairs),
-        _tt_iface_cols(in_pairs, n_left),
+        _tt_iface_cols(in_pairs),
     )
 
 
@@ -410,7 +408,7 @@ def predict_tt(
         t_factor=tf,
         t_out=to,
         t_compute=tc,
-        vmem_bytes=_tt_vmem(cfg, in_pairs, out_pair, n_left),
+        vmem_bytes=_tt_vmem(cfg, in_pairs, out_pair),
         nblocks=plan.nblocks,
         padding_fraction=plan.padding_fraction(),
     )
@@ -437,7 +435,7 @@ def predict_tt_analytic(
         t_factor=tf,
         t_out=to,
         t_compute=tc,
-        vmem_bytes=_tt_vmem(cfg, in_pairs, out_pair, n_left),
+        vmem_bytes=_tt_vmem(cfg, in_pairs, out_pair),
         nblocks=nblocks,
         padding_fraction=padding,
     )
@@ -542,12 +540,12 @@ def _feasible_configs(
                 tuple(_rank_padded(r) for r in in_ranks),
             )
         elif kernel == "tt":
-            in_pairs, out_pair, n_left = kernel_ranks
+            in_pairs, out_pair, _ = kernel_ranks
             fits = cfg.fits_tt(
                 spec,
                 _rank_padded(out_pair[0] * out_pair[1]),
                 tuple(_rank_padded(a * b) for a, b in in_pairs),
-                _tt_iface_cols(in_pairs, n_left),
+                _tt_iface_cols(in_pairs),
             )
         else:
             fits = cfg.fits(spec, _rank_padded(rank), n_in=n_in)
@@ -679,7 +677,7 @@ def _empty_shard_estimate(
     if kernel == "ttmc":
         vmem = _ttmc_vmem(cfg, kernel_ranks)
     elif kernel == "tt":
-        vmem = _tt_vmem(cfg, *kernel_ranks)
+        vmem = _tt_vmem(cfg, *kernel_ranks[:2])
     else:
         vmem = cfg.vmem_bytes(_rank_padded(rank), n_in=n_in)
     return PMSEstimate(

@@ -1,41 +1,22 @@
 """Blocked sorted-COO MTTKRP Pallas kernel — the memory controller in silicon.
 
-Engine mapping (DESIGN.md Sec. 2):
-  * DMA Engine      — the non-zero stream arrives as (nblocks, blk) BlockSpec
-                      tiles; Pallas double-buffers consecutive grid steps
-                      (HBM->VMEM DMA overlap with compute).
-  * Cache Engine    — one (tile_n x R_pad) factor tile per *input* mode is
-                      selected per block via scalar-prefetched tile ids; Pallas
-                      skips the copy when the id repeats between consecutive
-                      blocks, so the BlockPlan's run-length structure IS the
-                      cache-hit behaviour. Random access happens as an in-VMEM
-                      row gather.
-  * Approach 1      — blocks are sorted by output tile (Tensor Remapper), so
-                      the accumulator tile is resident across its whole run and
-                      flushed to HBM exactly once (no DRAM partial sums).
-  * MXU             — per-block segment accumulation is a one-hot matmul
-                      (tile_i x blk) @ (blk x R_pad) on the systolic array.
-
-The kernel body is template-unrolled over the number of input modes (N-1 for
-an N-mode tensor): `_kernel(tile_i, n_in, ...)` multiplies one gathered row
-set per input factor, so 3-, 4- and 5-mode tensors (paper Table 2) all run on
-the same generator.
-
-Validated in interpret=True mode against kernels/ref.py (CPU container; TPU is
-the target).
+The scaffold (stream blocks, factor-tile selection, one-hot gathers, the
+Approach-1 accumulator, SMEM chunking) is `kernels/blocked.py`; MTTKRP's own
+part is the per-element product: the Hadamard product of the gathered rows
+of every input factor, at the shared lane padding R_pad.  The product is
+unrolled over the number of input modes (N-1 for an N-mode tensor), so 3-,
+4- and 5-mode tensors (paper Table 2) all run on the same generator.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Sequence
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from ..core.remap import BlockPlan
+from .blocked import blocked_call
 
 __all__ = ["mttkrp_pallas_call", "pad_factor", "rank_padded"]
 
@@ -50,99 +31,26 @@ def pad_factor(f: jax.Array, rows: int, rp: int) -> jax.Array:
     return out.at[: f.shape[0], : f.shape[1]].set(f)
 
 
-def _kernel(tile_i: int, n_in: int, *refs):
-    """Template-unrolled kernel body for N-1 = n_in input factor tiles.
-
-    refs layout (after the grid-spec plumbing):
-      [0]                    it_ref           scalar-prefetch: output tile ids
-      [1 : 1+n_in]           input tile ids   (scalar-prefetch, unused in body)
-      [1+n_in]               vals_ref         (1, blk)
-      [2+n_in]               iloc_ref         (1, blk)
-      [3+n_in : 3+2*n_in]    input local idx  (1, blk) each
-      [3+2*n_in : 3+3*n_in]  factor tiles     (tile_n, rp) each
-      [3+3*n_in]             out_ref          (tile_i, rp)
-    """
-    it_ref = refs[0]
-    vals_ref = refs[1 + n_in]
-    iloc_ref = refs[2 + n_in]
-    loc_refs = refs[3 + n_in : 3 + 2 * n_in]
-    fac_refs = refs[3 + 2 * n_in : 3 + 3 * n_in]
-    out_ref = refs[3 + 3 * n_in]
-
-    b = pl.program_id(0)
-    # Approach-1 accumulator management: zero on the first block of each
-    # output tile's contiguous run (Tensor Remapper guarantees contiguity).
-    prev = jnp.maximum(b - 1, 0)
-    first_visit = jnp.logical_or(b == 0, it_ref[b] != it_ref[prev])
-
-    @pl.when(first_visit)
-    def _():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    vals = vals_ref[0, :]  # (blk,)
-    il = iloc_ref[0, :]
-
-    # Cache Engine: random row access served from the VMEM-resident tiles,
-    # one gather + Hadamard multiply per input mode.
-    contrib = vals[:, None].astype(jnp.float32)
-    for loc_ref, fac_ref in zip(loc_refs, fac_refs):
-        rows = jnp.take(fac_ref[...], loc_ref[0, :], axis=0)  # (blk, rp)
-        contrib = contrib * rows.astype(jnp.float32)
-
-    # MXU segment accumulation: one-hot (tile_i, blk) @ contrib (blk, rp).
-    rows = jax.lax.broadcasted_iota(jnp.int32, (tile_i, vals.shape[0]), 0)
-    onehot = (rows == il[None, :]).astype(jnp.float32)
-    out_ref[...] += jax.lax.dot(onehot, contrib, preferred_element_type=jnp.float32)
-
-
 @functools.partial(
-    jax.jit,
-    static_argnames=("tile_i", "in_tiles", "blk", "out_rows", "interpret"),
+    jax.jit, static_argnames=("tile_i", "in_tiles", "out_rows", "interpret")
 )
 def mttkrp_pallas_call(
     block_it: jax.Array,  # (nblocks,) int32
     block_in: Sequence[jax.Array],  # N-1 x (nblocks,) int32 input tile ids
-    vals: jax.Array,  # (nblocks, blk)
-    iloc: jax.Array,  # (nblocks, blk) int32
-    in_locs: Sequence[jax.Array],  # N-1 x (nblocks, blk) int32
+    vals: jax.Array,  # (nblocks, 1, blk)
+    iloc: jax.Array,  # (nblocks, 1, blk) int32
+    in_locs: Sequence[jax.Array],  # N-1 x (nblocks, 1, blk) int32
     factors_pad: Sequence[jax.Array],  # N-1 x (rows_n, rp), plan.in_modes order
     *,
     tile_i: int,
     in_tiles: tuple[int, ...],  # N-1 input tile sizes
-    blk: int,
     out_rows: int,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
-    block_in = tuple(block_in)
-    in_locs = tuple(in_locs)
-    factors_pad = tuple(factors_pad)
-    n_in = len(in_tiles)
-    assert len(block_in) == len(in_locs) == len(factors_pad) == n_in
-    nblocks = vals.shape[0]
-    rp = factors_pad[0].shape[1]
-
-    def stream_spec():
-        return pl.BlockSpec((1, blk), lambda b, it, *ts: (b, 0))
-
-    def factor_spec(n):
-        return pl.BlockSpec(
-            (in_tiles[n], rp), lambda b, it, *ts, n=n: (ts[n][b], 0)
-        )
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1 + n_in,  # output tile ids + one stream per input
-        grid=(nblocks,),
-        in_specs=(
-            [stream_spec()]  # vals (DMA stream)
-            + [stream_spec()]  # iloc
-            + [stream_spec() for _ in range(n_in)]  # input local indices
-            + [factor_spec(n) for n in range(n_in)]  # factor tiles (cache)
-        ),
-        out_specs=pl.BlockSpec((tile_i, rp), lambda b, it, *ts: (it[b], 0)),
+    """Returns (out_rows, rp) float32; `interpret=None` decides from the
+    platform."""
+    return blocked_call(
+        math.prod, block_it, block_in, vals, iloc, in_locs, factors_pad,
+        tile_i=tile_i, in_tiles=in_tiles, out_rows=out_rows,
+        out_cols=factors_pad[0].shape[1], interpret=interpret,
     )
-    return pl.pallas_call(
-        functools.partial(_kernel, tile_i, n_in),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((out_rows, rp), jnp.float32),
-        interpret=interpret,
-    )(block_it, *block_in, vals, iloc, *in_locs, *factors_pad)

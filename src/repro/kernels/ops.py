@@ -1,6 +1,5 @@
 """Jit'd wrappers for the decomposition kernels: plan construction + padding +
-dispatch between the Pallas kernels, their interpret-mode validation paths,
-and the pure-JAX references.
+dispatch between the Pallas kernels and the pure-JAX references.
 
 Three kernel families share the BlockPlan substrate (the memory controller is
 *programmable*, not MTTKRP-specific):
@@ -40,7 +39,6 @@ from typing import Any, Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.coo import SparseTensor
@@ -62,10 +60,8 @@ from .ttm_pallas import kron_cols, ttmc_pallas_call
 from .workspace import (
     PlannedWorkspace,
     ShardedWorkspace,
-    _apply_row_mask,
     _padded_rows_from,
     _plan_device_arrays,
-    _visited_row_mask,
     planned_layout_bytes,
     sharded_layout_bytes,
 )
@@ -113,14 +109,13 @@ class PlannedMTTKRP:
 
     plan: BlockPlan
     rank: int
-    interpret: bool
     cfg: MemoryControllerConfig = dataclasses.field(
         default_factory=MemoryControllerConfig
     )
-    _dev: dict = dataclasses.field(default_factory=dict)
+    layout: tuple = dataclasses.field(default=(), init=False, repr=False)
 
     def __post_init__(self):
-        self._dev = _plan_device_arrays(self.plan)
+        self.layout = _plan_device_arrays(self.plan)
 
     def __call__(self, *in_factors: jax.Array) -> jax.Array:
         """Factors for the N-1 *input* modes (plan.in_modes order).
@@ -132,19 +127,12 @@ class PlannedMTTKRP:
             pad_factor(f, rows, rp) for f, rows in zip(in_factors, p.in_rows)
         )
         out = mttkrp_pallas_call(
-            self._dev["block_it"],
-            self._dev["block_in"],
-            self._dev["vals"],
-            self._dev["iloc"],
-            self._dev["in_locs"],
+            *self.layout,
             pads,
             tile_i=p.tile_i,
             in_tiles=p.in_tiles,
-            blk=p.blk,
             out_rows=p.out_rows,
-            interpret=self.interpret,
         )
-        out = _apply_row_mask(out, self._dev["row_mask"])  # zero unvisited tiles
         return out[: p.out_rows, : self.rank]
 
     def output(self, factors: Sequence[jax.Array], true_rows: int) -> jax.Array:
@@ -189,7 +177,6 @@ def make_planned_mttkrp(
     cfg: MemoryControllerConfig | None = None,
     auto_tune: bool | str = False,
     spec: TPUSpec | str = TPUSpec(),
-    interpret: bool = True,
 ) -> PlannedMTTKRP:
     """Build the memory layout (Tensor Remapper) + kernel instance.  With
     auto_tune=True the PMS picks the controller parameters (Sec. 5.3);
@@ -216,7 +203,7 @@ def make_planned_mttkrp(
         blk=cfg.dma.blk,
         in_tiles=cfg.cache.input_tiles(n_in),
     )
-    return PlannedMTTKRP(plan=plan, rank=rank, interpret=interpret, cfg=cfg)
+    return PlannedMTTKRP(plan=plan, rank=rank, cfg=cfg)
 
 
 @dataclasses.dataclass
@@ -229,15 +216,14 @@ class PlannedTTMC:
 
     plan: BlockPlan
     in_ranks: tuple[int, ...]
-    interpret: bool
     cfg: MemoryControllerConfig = dataclasses.field(
         default_factory=MemoryControllerConfig
     )
-    _dev: dict = dataclasses.field(default_factory=dict)
+    layout: tuple = dataclasses.field(default=(), init=False, repr=False)
 
     def __post_init__(self):
         self.in_ranks = tuple(int(r) for r in self.in_ranks)
-        self._dev = _plan_device_arrays(self.plan)
+        self.layout = _plan_device_arrays(self.plan)
 
     @property
     def out_cols(self) -> int:
@@ -255,26 +241,22 @@ class PlannedTTMC:
         out = self.call_padded(pads)
         return out[: p.out_rows, : self.out_cols]
 
-    def call_padded(self, in_factors_pad: Sequence[jax.Array]) -> jax.Array:
+    def call_padded(self, in_factors_pad: Sequence[jax.Array], layout=None) -> jax.Array:
         """Run the kernel on already row/lane-padded input factors (the
         PlannedTucker sweep path).  Returns the padded (out_rows, Pp) tile
-        with unvisited output tiles zeroed."""
+        with unvisited output tiles zeroed.  Inside a jitted sweep pass this
+        op's `layout` in as a traced argument: arrays a jitted function
+        closes over are embedded in its program as constants."""
         p = self.plan
-        out = ttmc_pallas_call(
-            self._dev["block_it"],
-            self._dev["block_in"],
-            self._dev["vals"],
-            self._dev["iloc"],
-            self._dev["in_locs"],
+        layout = self.layout if layout is None else layout
+        return ttmc_pallas_call(
+            *layout,
             tuple(in_factors_pad),
             tile_i=p.tile_i,
             in_tiles=p.in_tiles,
             in_ranks=self.in_ranks,
-            blk=p.blk,
             out_rows=p.out_rows,
-            interpret=self.interpret,
         )
-        return _apply_row_mask(out, self._dev["row_mask"])
 
     def output(self, factors: Sequence[jax.Array], true_rows: int) -> jax.Array:
         return self(*(factors[m] for m in self.plan.in_modes))[:true_rows]
@@ -288,7 +270,6 @@ def make_planned_ttmc(
     cfg: MemoryControllerConfig | None = None,
     auto_tune: bool | str = False,
     spec: TPUSpec | str = TPUSpec(),
-    interpret: bool = True,
 ) -> PlannedTTMC:
     """Build the memory layout + TTMc kernel instance for one output mode.
 
@@ -304,7 +285,6 @@ def make_planned_ttmc(
       cfg / auto_tune / spec: controller configuration, or let the PMS tune
         it for the TTMc kernel specifically (the core-tensor output tile
         changes both the VMEM constraint and the roofline).
-      interpret: run the Pallas kernel in interpret mode.
 
     Returns:
       A `PlannedTTMC` holding the device-resident BlockPlan layout — the
@@ -344,7 +324,7 @@ def make_planned_ttmc(
         in_tiles=cfg.cache.input_tiles(n_in),
     )
     in_ranks = tuple(core_ranks[m] for m in plan.in_modes)
-    return PlannedTTMC(plan=plan, in_ranks=in_ranks, interpret=interpret, cfg=cfg)
+    return PlannedTTMC(plan=plan, in_ranks=in_ranks, cfg=cfg)
 
 
 def _tt_bond_pairs(tt_ranks: Sequence[int], nmodes: int) -> tuple[tuple[int, int], ...]:
@@ -372,17 +352,16 @@ class PlannedTTCore:
 
     plan: BlockPlan
     in_rank_pairs: tuple[tuple[int, int], ...]
-    interpret: bool
     cfg: MemoryControllerConfig = dataclasses.field(
         default_factory=MemoryControllerConfig
     )
-    _dev: dict = dataclasses.field(default_factory=dict)
+    layout: tuple = dataclasses.field(default=(), init=False, repr=False)
 
     def __post_init__(self):
         self.in_rank_pairs = tuple(
             (int(a), int(b)) for a, b in self.in_rank_pairs
         )
-        self._dev = _plan_device_arrays(self.plan)
+        self.layout = _plan_device_arrays(self.plan)
 
     @property
     def n_left(self) -> int:
@@ -411,27 +390,22 @@ class PlannedTTCore:
         out = self.call_padded(pads)
         return out[: p.out_rows, : self.out_cols]
 
-    def call_padded(self, in_mats_pad: Sequence[jax.Array]) -> jax.Array:
+    def call_padded(self, in_mats_pad: Sequence[jax.Array], layout=None) -> jax.Array:
         """Run the kernel on already row/lane-padded interface matrices (the
         PlannedTT sweep path).  Returns the padded (out_rows, Pp) tile with
-        unvisited output tiles zeroed."""
+        unvisited output tiles zeroed.  `layout` as in
+        `PlannedTTMC.call_padded`."""
         p = self.plan
-        out = ttcore_pallas_call(
-            self._dev["block_it"],
-            self._dev["block_in"],
-            self._dev["vals"],
-            self._dev["iloc"],
-            self._dev["in_locs"],
+        layout = self.layout if layout is None else layout
+        return ttcore_pallas_call(
+            *layout,
             tuple(in_mats_pad),
             tile_i=p.tile_i,
             in_tiles=p.in_tiles,
             in_rank_pairs=self.in_rank_pairs,
             n_left=self.n_left,
-            blk=p.blk,
             out_rows=p.out_rows,
-            interpret=self.interpret,
         )
-        return _apply_row_mask(out, self._dev["row_mask"])
 
     def output(self, mats: Sequence[jax.Array], true_rows: int) -> jax.Array:
         return self(*(mats[m] for m in self.plan.in_modes))[:true_rows]
@@ -445,7 +419,6 @@ def make_planned_ttcore(
     cfg: MemoryControllerConfig | None = None,
     auto_tune: bool | str = False,
     spec: TPUSpec | str = TPUSpec(),
-    interpret: bool = True,
 ) -> PlannedTTCore:
     """Build the memory layout + TT-core kernel instance for one output mode.
 
@@ -462,7 +435,6 @@ def make_planned_ttcore(
       cfg / auto_tune / spec: controller configuration, or let the PMS tune
         it for the TT kernel specifically (two interface scratch chains
         change the VMEM constraint and the roofline).
-      interpret: run the Pallas kernel in interpret mode.
 
     Returns:
       A `PlannedTTCore` holding the device-resident BlockPlan layout — the
@@ -498,7 +470,7 @@ def make_planned_ttcore(
     )
     in_rank_pairs = tuple(pairs[m] for m in plan.in_modes)
     return PlannedTTCore(
-        plan=plan, in_rank_pairs=in_rank_pairs, interpret=interpret, cfg=cfg
+        plan=plan, in_rank_pairs=in_rank_pairs, cfg=cfg
     )
 
 
@@ -546,28 +518,21 @@ class PlannedCPALS(PlannedWorkspace):
         rp, prows = self.rank_pad, self.padded_rows
         ops = self.ops
 
-        def sweep(facs, idx, val, norm_x_sq, first):
+        def sweep(layouts, facs, idx, val, norm_x_sq, first):
             facs = list(facs)
             lam = None
             for m in range(nmodes):
-                op, p = ops[m], ops[m].plan
+                p = ops[m].plan
                 in_facs = tuple(
                     facs[im][: p.in_rows[n]] for n, im in enumerate(p.in_modes)
                 )
                 out = mttkrp_pallas_call(
-                    op._dev["block_it"],
-                    op._dev["block_in"],
-                    op._dev["vals"],
-                    op._dev["iloc"],
-                    op._dev["in_locs"],
+                    *layouts[m],
                     in_facs,
                     tile_i=p.tile_i,
                     in_tiles=p.in_tiles,
-                    blk=p.blk,
                     out_rows=p.out_rows,
-                    interpret=op.interpret,
                 )
-                out = _apply_row_mask(out, op._dev["row_mask"])  # zero unvisited tiles
                 mt = out[: shape[m], :rank]
                 true = [f[:s, :rank] for f, s in zip(facs, shape)]
                 true, lam = _update_mode(mt, true, m, first)
@@ -653,7 +618,6 @@ def make_planned_cp_als(
     cfg: MemoryControllerConfig | None = None,
     auto_tune: bool | str = False,
     spec: TPUSpec | str = TPUSpec(),
-    interpret: bool = True,
 ) -> PlannedCPALS:
     """Build the full ALS workspace: one tuned plan per output mode.
 
@@ -668,7 +632,6 @@ def make_planned_cp_als(
       auto_tune: run the PMS per output mode (modes have different shapes /
         locality, Sec. 5.3) and take each mode's best configuration.
       spec: target-hardware constants for the PMS search.
-      interpret: run the Pallas kernels in interpret mode (CPU containers).
 
     Returns:
       A `PlannedCPALS` whose per-mode remapped layouts are device-resident
@@ -677,7 +640,7 @@ def make_planned_cp_als(
       calls to skip the remap entirely."""
     ops = {
         m: make_planned_mttkrp(
-            st, m, rank, cfg=cfg, auto_tune=auto_tune, spec=spec, interpret=interpret
+            st, m, rank, cfg=cfg, auto_tune=auto_tune, spec=spec
         )
         for m in range(st.nmodes)
     }
@@ -766,13 +729,12 @@ def _planned_cached(
     mode: int,
     rank_key,
     cfg: MemoryControllerConfig | None,
-    interpret: bool,
     build: Callable,
     *,
     shard: tuple | None = None,
 ):
     """LRU-cached plan lookup keyed by (kernel kind, tensor content
-    fingerprint, mode, rank key, controller config, interpret, shard) —
+    fingerprint, mode, rank key, controller config, shard) —
     repeated test/benchmark calls stop repaying the Tensor Remapper on every
     invocation.  The leading `kind` field keeps MTTKRP and TTMc plans for
     the same tensor/mode/rank from silently aliasing each other: the cached
@@ -789,7 +751,6 @@ def _planned_cached(
         mode,
         rank_key,
         cfg or MemoryControllerConfig(),
-        bool(interpret),
         shard,
     )
     stats = _PLAN_CACHE_STATS[kind]
@@ -828,7 +789,6 @@ def mttkrp_auto(
     mode: int,
     *,
     method: str = "pallas",
-    interpret: bool = True,
     cfg: MemoryControllerConfig | None = None,
     sorted_by_mode: bool | None = None,
 ) -> jax.Array:
@@ -842,8 +802,8 @@ def mttkrp_auto(
     rank = int(factors[0].shape[1])
     if method == "pallas":
         op = _planned_cached(
-            "mttkrp", st, mode, rank, cfg, interpret,
-            lambda: make_planned_mttkrp(st, mode, rank, cfg=cfg, interpret=interpret),
+            "mttkrp", st, mode, rank, cfg,
+            lambda: make_planned_mttkrp(st, mode, rank, cfg=cfg),
         )
         return op.output(factors, st.shape[mode])
     if sorted_by_mode is None:
@@ -861,7 +821,6 @@ def tucker_auto(
     mode: int,
     *,
     method: str = "pallas",
-    interpret: bool = True,
     cfg: MemoryControllerConfig | None = None,
 ) -> jax.Array:
     """One-shot sparse TTM-chain dispatcher (the Tucker-side analogue of
@@ -877,7 +836,7 @@ def tucker_auto(
         cached in the shared kind-keyed LRU (see
         `plan_cache_stats()["by_kind"]["ttmc"]`); 'reference' — the pure-jnp
         gather/Kronecker/segment_sum oracle.
-      interpret / cfg: pallas-path knobs (both are part of the cache key).
+      cfg: pallas-path controller configuration (part of the cache key).
 
     Returns:
       The unfolding Y_(mode), shape (I_mode, prod of input ranks), float32,
@@ -889,8 +848,8 @@ def tucker_auto(
     if method == "pallas":
         in_ranks = tuple(r for m, r in enumerate(core_ranks) if m != mode)
         op = _planned_cached(
-            "ttmc", st, mode, in_ranks, cfg, interpret,
-            lambda: make_planned_ttmc(st, mode, core_ranks, cfg=cfg, interpret=interpret),
+            "ttmc", st, mode, in_ranks, cfg,
+            lambda: make_planned_ttmc(st, mode, core_ranks, cfg=cfg),
         )
         return op.output(factors, st.shape[mode])
     if method != "reference":
@@ -906,7 +865,6 @@ def tt_auto(
     mode: int,
     *,
     method: str = "pallas",
-    interpret: bool = True,
     cfg: MemoryControllerConfig | None = None,
 ) -> jax.Array:
     """One-shot sparse TT-core dispatcher (the tensor-train analogue of
@@ -923,7 +881,7 @@ def tt_auto(
         cached in the shared kind-keyed LRU (see
         `plan_cache_stats()["by_kind"]["tt"]`); 'reference' — the pure-jnp
         gather/chain/segment_sum oracle.
-      interpret / cfg: pallas-path knobs (both are part of the cache key).
+      cfg: pallas-path controller configuration (part of the cache key).
 
     Returns:
       B_mode, shape (I_mode, rl_mode * rr_mode), float32, columns row-major
@@ -935,8 +893,8 @@ def tt_auto(
         in_pairs = tuple(p for m, p in enumerate(pairs) if m != mode)
         tt_ranks = tuple(pairs[k][1] for k in range(len(cores) - 1))
         op = _planned_cached(
-            "tt", st, mode, in_pairs, cfg, interpret,
-            lambda: make_planned_ttcore(st, mode, tt_ranks, cfg=cfg, interpret=interpret),
+            "tt", st, mode, in_pairs, cfg,
+            lambda: make_planned_ttcore(st, mode, tt_ranks, cfg=cfg),
         )
         mats = [jnp.transpose(c, (1, 0, 2)).reshape(c.shape[1], -1) for c in cores]
         return op.output(mats, st.shape[mode])
@@ -972,10 +930,9 @@ class _ShardStack:
 
     block_it: jax.Array  # (D, NB) int32 — global output tile ids
     block_in: tuple  # n_in x (D, NB) int32
-    vals: jax.Array  # (D, NB, blk) f32
-    iloc: jax.Array  # (D, NB, blk) int32
-    in_locs: tuple  # n_in x (D, NB, blk) int32
-    row_mask: jax.Array  # (D, out_rows) f32 — 1.0 on each shard's visited tiles
+    vals: jax.Array  # (D, NB, 1, blk) f32
+    iloc: jax.Array  # (D, NB, 1, blk) int32
+    in_locs: tuple  # n_in x (D, NB, 1, blk) int32
     tile_i: int
     in_tiles: tuple[int, ...]
     blk: int
@@ -1008,19 +965,17 @@ class _ShardStack:
             "vals": self.vals,
             "iloc": self.iloc,
             "in_locs": self.in_locs,
-            "row_mask": self.row_mask,
         }
 
     def tree_specs(self, axes) -> dict:
         """PartitionSpecs matching `tree()`: leading dim over the data axes."""
-        row, cube = P(axes, None), P(axes, None, None)
+        row, cube = P(axes, None), P(axes, None, None, None)
         return {
             "block_it": row,
             "block_in": tuple(row for _ in self.block_in),
             "vals": cube,
             "iloc": cube,
             "in_locs": tuple(cube for _ in self.in_locs),
-            "row_mask": row,
         }
 
 
@@ -1065,14 +1020,11 @@ def _stack_shard_plans(plans: Sequence[BlockPlan], part, dist) -> _ShardStack:
     nd = len(plans)
     nb = max(p.nblocks for p in plans)
     n_in, blk = p0.n_in, p0.blk
-    row_mask = np.stack(
-        [_visited_row_mask(p.block_it, p.tile_i, p.out_rows) for p in plans]
-    )
     block_it = np.zeros((nd, nb), np.int32)
     block_in = [np.zeros((nd, nb), np.int32) for _ in range(n_in)]
-    vals = np.zeros((nd, nb, blk), np.float32)
-    iloc = np.zeros((nd, nb, blk), np.int32)
-    in_locs = [np.zeros((nd, nb, blk), np.int32) for _ in range(n_in)]
+    vals = np.zeros((nd, nb, 1, blk), np.float32)
+    iloc = np.zeros((nd, nb, 1, blk), np.int32)
+    in_locs = [np.zeros((nd, nb, 1, blk), np.int32) for _ in range(n_in)]
     for d, p in enumerate(plans):
         k = p.nblocks
         block_it[d, :k] = p.block_it
@@ -1080,20 +1032,19 @@ def _stack_shard_plans(plans: Sequence[BlockPlan], part, dist) -> _ShardStack:
         for n in range(n_in):
             block_in[n][d, :k] = p.block_in[n]
             block_in[n][d, k:] = p.block_in[n][-1]
-        vals[d, :k] = p.vals.reshape(k, blk)
-        iloc[d, :k] = p.iloc.reshape(k, blk)
+        vals[d, :k] = p.vals.reshape(k, 1, blk)
+        iloc[d, :k] = p.iloc.reshape(k, 1, blk)
         for n in range(n_in):
-            in_locs[n][d, :k] = p.in_locs[n].reshape(k, blk)
+            in_locs[n][d, :k] = p.in_locs[n].reshape(k, 1, blk)
     mesh, axes = dist.mesh, dist.data_axes()
     sh_row = NamedSharding(mesh, P(axes, None))
-    sh_cube = NamedSharding(mesh, P(axes, None, None))
+    sh_cube = NamedSharding(mesh, P(axes, None, None, None))
     return _ShardStack(
         block_it=jax.device_put(block_it, sh_row),
         block_in=tuple(jax.device_put(b, sh_row) for b in block_in),
         vals=jax.device_put(vals, sh_cube),
         iloc=jax.device_put(iloc, sh_cube),
         in_locs=tuple(jax.device_put(l, sh_cube) for l in in_locs),
-        row_mask=jax.device_put(row_mask, sh_row),
         tile_i=p0.tile_i,
         in_tiles=p0.in_tiles,
         blk=blk,
@@ -1119,9 +1070,8 @@ def _sharded_mode_stack(
     keys (`_planned_cached(shard=(d, nshards))`), so rebuilding a workspace
     for the same tensor skips the per-shard Tensor Remapper.  The cached
     objects are raw BlockPlans, which depend only on (stream, mode, cfg) —
-    the rank key is a constant sentinel and interpret is pinned False, so
-    rebuilding the same tensor at a different rank or interpret flag still
-    hits.  Returns (partition, stack)."""
+    the rank key is a constant sentinel, so rebuilding the same tensor at a
+    different rank still hits.  Returns (partition, stack)."""
     from ..dist.sharding import partition_stream
 
     nshards = dist.dp_size()
@@ -1135,7 +1085,7 @@ def _sharded_mode_stack(
                 continue
             plans.append(
                 _planned_cached(
-                    kind, shard, mode, "layout", cfg, False,
+                    kind, shard, mode, "layout", cfg,
                     lambda shard=shard: plan_blocks(
                         shard,
                         mode,
@@ -1175,52 +1125,35 @@ def _stack_fit_stream(part, shape: tuple[int, ...], dist):
     )
 
 
-def _stack_mttkrp_call(stack: _ShardStack, arrs: dict, in_facs, interpret: bool) -> jax.Array:
-    """One shard's MTTKRP kernel over its row of the stack (inside shard_map
-    every stacked array arrives with a leading local dim of 1).
-
-    The result is multiplied by the shard's visited-row mask: the kernel's
-    output buffer is only *written* for tiles its blocks visit; every other
-    tile — outside the shard's partition range OR inside it but owning no
-    non-zeros — keeps whatever the buffer held (NaNs in interpret mode,
-    undefined on hardware).  Masking to the visited tiles zeroes both kinds
-    and makes the psum a pure reassembly of disjoint contributions."""
-    out = mttkrp_pallas_call(
+def _stack_args(arrs: dict) -> tuple:
+    """One shard's row of a stack as the kernels' leading arguments (inside
+    shard_map every stacked array arrives with a leading local dim of 1).
+    The kernels zero every output tile their blocks do not visit, so the
+    psum over shards is a pure reassembly of disjoint contributions."""
+    return (
         arrs["block_it"][0],
         tuple(t[0] for t in arrs["block_in"]),
         arrs["vals"][0],
         arrs["iloc"][0],
         tuple(l[0] for l in arrs["in_locs"]),
-        in_facs,
-        tile_i=stack.tile_i,
-        in_tiles=stack.in_tiles,
-        blk=stack.blk,
-        out_rows=stack.out_rows,
-        interpret=interpret,
     )
-    return _apply_row_mask(out, arrs["row_mask"][0])
+
+
+def _stack_mttkrp_call(stack: _ShardStack, arrs: dict, in_facs) -> jax.Array:
+    return mttkrp_pallas_call(
+        *_stack_args(arrs), in_facs,
+        tile_i=stack.tile_i, in_tiles=stack.in_tiles, out_rows=stack.out_rows,
+    )
 
 
 def _stack_ttmc_call(
-    stack: _ShardStack, arrs: dict, in_facs, in_ranks: tuple[int, ...], interpret: bool
+    stack: _ShardStack, arrs: dict, in_facs, in_ranks: tuple[int, ...]
 ) -> jax.Array:
-    """One shard's TTM-chain kernel over its row of the stack (visited-row
-    masked — see `_stack_mttkrp_call`)."""
-    out = ttmc_pallas_call(
-        arrs["block_it"][0],
-        tuple(t[0] for t in arrs["block_in"]),
-        arrs["vals"][0],
-        arrs["iloc"][0],
-        tuple(l[0] for l in arrs["in_locs"]),
-        in_facs,
-        tile_i=stack.tile_i,
-        in_tiles=stack.in_tiles,
-        in_ranks=in_ranks,
-        blk=stack.blk,
+    return ttmc_pallas_call(
+        *_stack_args(arrs), in_facs,
+        tile_i=stack.tile_i, in_tiles=stack.in_tiles, in_ranks=in_ranks,
         out_rows=stack.out_rows,
-        interpret=interpret,
     )
-    return _apply_row_mask(out, arrs["row_mask"][0])
 
 
 def _stack_ttcore_call(
@@ -1229,26 +1162,12 @@ def _stack_ttcore_call(
     in_mats,
     in_rank_pairs: tuple[tuple[int, int], ...],
     n_left: int,
-    interpret: bool,
 ) -> jax.Array:
-    """One shard's TT-core kernel over its row of the stack (visited-row
-    masked — see `_stack_mttkrp_call`)."""
-    out = ttcore_pallas_call(
-        arrs["block_it"][0],
-        tuple(t[0] for t in arrs["block_in"]),
-        arrs["vals"][0],
-        arrs["iloc"][0],
-        tuple(l[0] for l in arrs["in_locs"]),
-        in_mats,
-        tile_i=stack.tile_i,
-        in_tiles=stack.in_tiles,
-        in_rank_pairs=in_rank_pairs,
-        n_left=n_left,
-        blk=stack.blk,
-        out_rows=stack.out_rows,
-        interpret=interpret,
+    return ttcore_pallas_call(
+        *_stack_args(arrs), in_mats,
+        tile_i=stack.tile_i, in_tiles=stack.in_tiles,
+        in_rank_pairs=in_rank_pairs, n_left=n_left, out_rows=stack.out_rows,
     )
-    return _apply_row_mask(out, arrs["row_mask"][0])
 
 
 def _tuned_cfg(
@@ -1326,28 +1245,27 @@ class ShardedPlannedMTTKRP:
     stack: _ShardStack
     dist: Any  # ShardingPlan with mesh + data axes
     rank: int
-    interpret: bool
     cfg: MemoryControllerConfig = dataclasses.field(
         default_factory=MemoryControllerConfig
     )
     _call_fn: Callable | None = dataclasses.field(default=None, repr=False)
 
     def _build_call(self) -> Callable:
-        stack, interpret = self.stack, self.interpret
+        stack = self.stack
         mesh, axes = self.dist.mesh, self.dist.data_axes()
         fac_specs = tuple(P(None, None) for _ in range(stack.n_in))
 
         def local_fn(arrs, pads):
-            out = _stack_mttkrp_call(stack, arrs, pads, interpret)
+            out = _stack_mttkrp_call(stack, arrs, pads)
             return jax.lax.psum(out, axes)
 
         def call(arrs, pads):
-            return shard_map(
+            return jax.shard_map(
                 local_fn,
                 mesh=mesh,
                 in_specs=(stack.tree_specs(axes), fac_specs),
                 out_specs=P(None, None),
-                check_rep=False,
+                check_vma=False,
             )(arrs, pads)
 
         return jax.jit(call)
@@ -1380,7 +1298,6 @@ def make_sharded_planned_mttkrp(
     cfg: MemoryControllerConfig | None = None,
     auto_tune: bool | str = False,
     spec: TPUSpec | str = TPUSpec(),
-    interpret: bool = True,
 ) -> ShardedPlannedMTTKRP:
     """Build the distributed memory layout + kernel instance for one output
     mode.  With auto_tune=True the PMS scores configurations by their *worst
@@ -1389,7 +1306,7 @@ def make_sharded_planned_mttkrp(
     cfg = _tuned_cfg(st, mode, rank, dist.dp_size(), cfg, auto_tune, spec)
     _, stack = _sharded_mode_stack(st, mode, cfg, dist, "mttkrp")
     return ShardedPlannedMTTKRP(
-        stack=stack, dist=dist, rank=rank, interpret=interpret, cfg=cfg
+        stack=stack, dist=dist, rank=rank, cfg=cfg
     )
 
 
@@ -1413,7 +1330,6 @@ class ShardedPlannedCPALS(ShardedWorkspace):
     dist: Any  # ShardingPlan with mesh + data axes
     shape: tuple[int, ...]
     rank: int
-    interpret: bool
     cfgs: dict[int, MemoryControllerConfig]
     idx_sh: jax.Array  # (D, max shard nnz, N) fit stream, zero-padded
     val_sh: jax.Array  # (D, max shard nnz)
@@ -1433,7 +1349,7 @@ class ShardedPlannedCPALS(ShardedWorkspace):
     def _build_sweep(self) -> Callable:
         shape, rank, nmodes = self.shape, self.rank, self.nmodes
         rp, prows = self.rank_pad, self.padded_rows
-        stacks, interpret = self.stacks, self.interpret
+        stacks = self.stacks
         mesh, axes = self.dist.mesh, self.dist.data_axes()
         arr_specs = {m: stacks[m].tree_specs(axes) for m in range(nmodes)}
         fac_specs = tuple(P(None, None) for _ in range(nmodes))
@@ -1446,7 +1362,7 @@ class ShardedPlannedCPALS(ShardedWorkspace):
                 in_facs = tuple(
                     facs[im][: s.in_rows[n]] for n, im in enumerate(s.in_modes)
                 )
-                out = _stack_mttkrp_call(s, arrs[m], in_facs, interpret)
+                out = _stack_mttkrp_call(s, arrs[m], in_facs)
                 # The single collective per mode: partial factor rows from
                 # disjoint tile ranges -> the full MTTKRP output.
                 mt = jax.lax.psum(out, axes)[: shape[m], :rank]
@@ -1468,7 +1384,7 @@ class ShardedPlannedCPALS(ShardedWorkspace):
 
         def sweep(arrs, idx_sh, val_sh, facs, norm_x_sq, first):
             fn = functools.partial(local_sweep, first=first)
-            return shard_map(
+            return jax.shard_map(
                 fn,
                 mesh=mesh,
                 in_specs=(
@@ -1479,7 +1395,7 @@ class ShardedPlannedCPALS(ShardedWorkspace):
                     P(),
                 ),
                 out_specs=(fac_specs, P(None), P()),
-                check_rep=False,
+                check_vma=False,
             )(arrs, idx_sh, val_sh, facs, norm_x_sq)
 
         return jax.jit(sweep, static_argnames=("first",))
@@ -1507,7 +1423,6 @@ def make_sharded_planned_cp_als(
     cfg: MemoryControllerConfig | None = None,
     auto_tune: bool | str = False,
     spec: TPUSpec | str = TPUSpec(),
-    interpret: bool = True,
 ) -> ShardedPlannedCPALS:
     """Build the distributed ALS workspace: one partition + shard-stacked
     layout per output mode (each mode partitions by ITS OWN output
@@ -1534,7 +1449,6 @@ def make_sharded_planned_cp_als(
         dist=dist,
         shape=st.shape,
         rank=rank,
-        interpret=interpret,
         cfgs=cfgs,
         idx_sh=idx_sh,
         val_sh=val_sh,
@@ -1554,7 +1468,6 @@ class ShardedPlannedTucker(ShardedWorkspace):
     dist: Any
     shape: tuple[int, ...]
     core_ranks: tuple[int, ...]
-    interpret: bool
     cfgs: dict[int, MemoryControllerConfig]
 
     @property
@@ -1574,7 +1487,7 @@ class ShardedPlannedTucker(ShardedWorkspace):
 
         shape, core_ranks, nmodes = self.shape, self.core_ranks, self.nmodes
         rps, prows = self.rank_pads, self.padded_rows
-        stacks, interpret = self.stacks, self.interpret
+        stacks = self.stacks
         mesh, axes = self.dist.mesh, self.dist.data_axes()
         in_ranks = {m: self.in_ranks(m) for m in range(nmodes)}
         out_cols = {m: kron_cols(in_ranks[m]) for m in range(nmodes)}
@@ -1589,7 +1502,7 @@ class ShardedPlannedTucker(ShardedWorkspace):
                 in_facs = tuple(
                     facs[im][: s.in_rows[n]] for n, im in enumerate(s.in_modes)
                 )
-                out = _stack_ttmc_call(s, arrs[m], in_facs, in_ranks[m], interpret)
+                out = _stack_ttmc_call(s, arrs[m], in_facs, in_ranks[m])
                 y = jax.lax.psum(out, axes)[: shape[m], : out_cols[m]]
                 u = _factor_from_unfolding(y, core_ranks[m])
                 facs[m] = (
@@ -1603,12 +1516,12 @@ class ShardedPlannedTucker(ShardedWorkspace):
             return tuple(facs), core, core_fit_value(core, norm_x_sq)
 
         def sweep(arrs, facs, norm_x_sq):
-            return shard_map(
+            return jax.shard_map(
                 local_sweep,
                 mesh=mesh,
                 in_specs=(arr_specs, fac_specs, P()),
                 out_specs=(fac_specs, P(*([None] * nmodes)), P()),
-                check_rep=False,
+                check_vma=False,
             )(arrs, facs, norm_x_sq)
 
         return jax.jit(sweep)
@@ -1629,7 +1542,6 @@ def make_sharded_planned_tucker(
     cfg: MemoryControllerConfig | None = None,
     auto_tune: bool | str = False,
     spec: TPUSpec | str = TPUSpec(),
-    interpret: bool = True,
 ) -> ShardedPlannedTucker:
     """Build the distributed HOOI workspace: one partition + shard-stacked
     TTMc layout per output mode.  Mirrors `make_sharded_planned_cp_als`;
@@ -1654,7 +1566,6 @@ def make_sharded_planned_tucker(
         dist=dist,
         shape=st.shape,
         core_ranks=cr,
-        interpret=interpret,
         cfgs=cfgs,
     )
 
@@ -1674,7 +1585,6 @@ class ShardedPlannedTT(ShardedWorkspace):
     dist: Any
     shape: tuple[int, ...]
     tt_ranks: tuple[int, ...]  # N-1 interior bond ranks
-    interpret: bool
     cfgs: dict[int, MemoryControllerConfig]
     idx_sh: jax.Array  # (D, max shard nnz, N) fit stream, zero-padded
     val_sh: jax.Array  # (D, max shard nnz)
@@ -1701,7 +1611,7 @@ class ShardedPlannedTT(ShardedWorkspace):
         shape, nmodes = self.shape, self.nmodes
         pairs, lr = self.bond_pairs, self.lane_ranks
         rps, prows = self.rank_pads, self.padded_rows
-        stacks, interpret = self.stacks, self.interpret
+        stacks = self.stacks
         mesh, axes = self.dist.mesh, self.dist.data_axes()
         in_pairs = {m: self.in_rank_pairs(m) for m in range(nmodes)}
         arr_specs = {m: stacks[m].tree_specs(axes) for m in range(nmodes)}
@@ -1723,7 +1633,7 @@ class ShardedPlannedTT(ShardedWorkspace):
                 in_mats = tuple(
                     facs[im][: s.in_rows[n]] for n, im in enumerate(s.in_modes)
                 )
-                out = _stack_ttcore_call(s, arrs[m], in_mats, in_pairs[m], m, interpret)
+                out = _stack_ttcore_call(s, arrs[m], in_mats, in_pairs[m], m)
                 # The single collective per mode: partial right-hand-side
                 # rows from disjoint tile ranges -> the full B_m.
                 b = jax.lax.psum(out, axes)[: shape[m], : lr[m]]
@@ -1744,7 +1654,7 @@ class ShardedPlannedTT(ShardedWorkspace):
             return tuple(facs), fit
 
         def sweep(arrs, idx_sh, val_sh, facs, norm_x_sq):
-            facs, fit = shard_map(
+            facs, fit = jax.shard_map(
                 local_sweep,
                 mesh=mesh,
                 in_specs=(
@@ -1755,7 +1665,7 @@ class ShardedPlannedTT(ShardedWorkspace):
                     P(),
                 ),
                 out_specs=(fac_specs, P()),
-                check_rep=False,
+                check_vma=False,
             )(arrs, idx_sh, val_sh, facs, norm_x_sq)
             return facs, None, fit
 
@@ -1777,7 +1687,6 @@ def make_sharded_planned_tt(
     cfg: MemoryControllerConfig | None = None,
     auto_tune: bool | str = False,
     spec: TPUSpec | str = TPUSpec(),
-    interpret: bool = True,
 ) -> ShardedPlannedTT:
     """Build the distributed TT-ALS workspace: one partition + shard-stacked
     TT-core layout per output mode.  Mirrors `make_sharded_planned_cp_als`;
@@ -1806,7 +1715,6 @@ def make_sharded_planned_tt(
         dist=dist,
         shape=st.shape,
         tt_ranks=tr,
-        interpret=interpret,
         cfgs=cfgs,
         idx_sh=idx_sh,
         val_sh=val_sh,

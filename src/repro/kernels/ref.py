@@ -82,13 +82,14 @@ def ttmc_ref(
     """Sparse TTM-chain: Y[i_n, :] += v * kron(rows of every factor != mode),
     columns in row-major order over ascending input-mode index.  `factors`
     holds all N factor matrices; the mode-th is ignored.  Returns
-    (out_rows, prod of input ranks)."""
+    (out_rows, prod of input ranks), at f32 or the inputs' wider type."""
     nnz = values.shape[0]
-    contrib = values[:, None].astype(jnp.float32)
+    dt = jnp.promote_types(values.dtype, jnp.float32)
+    contrib = values[:, None].astype(dt)
     for n, f in enumerate(factors):
         if n == mode:
             continue
-        rows = f[indices[:, n]].astype(jnp.float32)  # (nnz, R_n)
+        rows = f[indices[:, n]].astype(dt)  # (nnz, R_n)
         contrib = (contrib[:, :, None] * rows[:, None, :]).reshape(nnz, -1)
     return jax.ops.segment_sum(contrib, indices[:, mode], num_segments=out_rows)
 
@@ -153,17 +154,18 @@ def ttcore_ref(
     the left interface chain over cores < mode and r the right chain over
     cores > mode, columns row-major over (rl_m, rr_m).  `cores` holds all N
     TT cores, shape (rl_k, I_k, rr_k); the mode-th is ignored.  Returns
-    (out_rows, rl_m * rr_m)."""
+    (out_rows, rl_m * rr_m), at f32 or the inputs' wider type."""
     nnz = values.shape[0]
-    left = jnp.ones((nnz, 1), jnp.float32)
+    dt = jnp.promote_types(values.dtype, jnp.float32)
+    left = jnp.ones((nnz, 1), dt)
     for k in range(mode):
         rows = jnp.transpose(cores[k], (1, 0, 2))[indices[:, k]]  # (nnz, rl, rr)
-        left = jnp.einsum("za,zab->zb", left, rows.astype(jnp.float32))
-    right = jnp.ones((nnz, 1), jnp.float32)
+        left = jnp.einsum("za,zab->zb", left, rows.astype(dt))
+    right = jnp.ones((nnz, 1), dt)
     for k in range(len(cores) - 1, mode, -1):
         rows = jnp.transpose(cores[k], (1, 0, 2))[indices[:, k]]
-        right = jnp.einsum("zab,zb->za", rows.astype(jnp.float32), right)
-    contrib = values[:, None].astype(jnp.float32) * (
+        right = jnp.einsum("zab,zb->za", rows.astype(dt), right)
+    contrib = values[:, None].astype(dt) * (
         left[:, :, None] * right[:, None, :]
     ).reshape(nnz, -1)
     return jax.ops.segment_sum(contrib, indices[:, mode], num_segments=out_rows)

@@ -12,28 +12,17 @@ r_z is the RIGHT interface chain G_{m+1}[:, i_{m+1}, :] ... G_{N-1} (a column
 vector of width rr_m, applied to a vector of ones from the right).  That is
 TTMc with the full Kronecker chain collapsed to a Kronecker of TWO chained
 interfaces — the irregular memory access pattern is IDENTICAL, so the kernel
-consumes the exact BlockPlan layout the Tensor Remapper builds for MTTKRP /
-TTMc.  Engine mapping is unchanged (see kernels/mttkrp_pallas.py):
+runs on the same scaffold (kernels/blocked.py) over the same BlockPlan.
 
-  * DMA Engine    — (nblocks, blk) BlockSpec stream tiles, double-buffered;
-  * Cache Engine  — one (tile_n x rank_padded(rl_n*rr_n)) core-interface tile
-                    per input mode, selected by scalar-prefetched tile ids;
-  * Approach 1    — blocks sorted by output tile: the (tile_i x Pp)
-                    accumulator is resident across its run, flushed once;
-  * MXU           — segment accumulation as a one-hot matmul
-                    (tile_i x blk) @ (blk x Pp).
-
-Differences from the TTMc kernel: each input factor is a core's interface
-matrix W_k = transpose(G_k, (1,0,2)).reshape(I_k, rl_k*rr_k) (row-major —
-rl slow, rr fast), lane-padded to rank_padded(rl_k*rr_k); gathered rows fold
-into the left chain (inputs left of the output mode, ascending) or the right
-chain (inputs right of it, descending) as (blk, rl, rr) matrix-vector
-products on the VPU, and the output carries rl_m * rr_m true columns
-(lane-padded to rank_padded(rl_m*rr_m)).  `plan.in_modes` is ascending, so
-n_left — the number of left-chain inputs — equals the output mode.
-
-Validated in interpret=True mode against kernels/ref.py (CPU container; TPU
-is the target).
+Each input factor is a core's interface matrix
+W_k = transpose(G_k, (1,0,2)).reshape(I_k, rl_k*rr_k) (row-major — rl slow,
+rr fast), lane-padded to rank_padded(rl_k*rr_k).  Gathered rows fold into
+the left chain (inputs left of the output mode, ascending) or the right
+chain (inputs right of it, descending).  Both chains are (blk, cw) vectors,
+cw the lane padding of the widest bond, and every step is a 0/1 matmul
+spread, an elementwise product and a 0/1 matmul reduction — no reshape
+splits a lane dimension.  `plan.in_modes` is ascending, so n_left — the
+number of left-chain inputs — equals the output mode.
 """
 from __future__ import annotations
 
@@ -42,9 +31,8 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
+from .blocked import blocked_call, dot, spread
 from .mttkrp_pallas import rank_padded
 
 __all__ = ["ttcore_pallas_call", "tt_out_pair", "tt_out_cols"]
@@ -69,143 +57,73 @@ def tt_out_cols(in_rank_pairs: Sequence[tuple[int, int]], n_left: int) -> int:
     return rl * rr
 
 
-def _kernel(
-    tile_i: int,
-    n_in: int,
-    in_rank_pairs: tuple[tuple[int, int], ...],
-    n_left: int,
-    *refs,
-):
-    """Template-unrolled kernel body for n_in core-interface tiles.
+def _chain_contract(
+    in_rank_pairs: tuple[tuple[int, int], ...], n_left: int, pp: int, rows: list
+) -> jax.Array:
+    """kron(left chain, right chain) of the gathered interface rows, (blk, pp).
 
-    refs layout is identical to the MTTKRP kernel (the plan layout is shared):
-      [0]                    it_ref           scalar-prefetch: output tile ids
-      [1 : 1+n_in]           input tile ids   (scalar-prefetch, unused in body)
-      [1+n_in]               vals_ref         (1, blk)
-      [2+n_in]               iloc_ref         (1, blk)
-      [3+n_in : 3+2*n_in]    input local idx  (1, blk) each
-      [3+2*n_in : 3+3*n_in]  interface tiles  (tile_n, rank_padded(rl*rr)) each
-      [3+3*n_in]             out_ref          (tile_i, Pp)
-    """
-    it_ref = refs[0]
-    vals_ref = refs[1 + n_in]
-    iloc_ref = refs[2 + n_in]
-    loc_refs = refs[3 + n_in : 3 + 2 * n_in]
-    fac_refs = refs[3 + 2 * n_in : 3 + 3 * n_in]
-    out_ref = refs[3 + 3 * n_in]
-
-    b = pl.program_id(0)
-    # Approach-1 accumulator management: zero on the first block of each
-    # output tile's contiguous run (Tensor Remapper guarantees contiguity).
-    prev = jnp.maximum(b - 1, 0)
-    first_visit = jnp.logical_or(b == 0, it_ref[b] != it_ref[prev])
-
-    @pl.when(first_visit)
-    def _():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    vals = vals_ref[0, :]  # (blk,)
-    il = iloc_ref[0, :]
-    blk = vals.shape[0]
-
-    def gathered(n):
-        """One input's interface rows as (blk, rl, rr), lane padding sliced
-        off before the chain so it never enters the product."""
-        rl, rr = in_rank_pairs[n]
-        rows = jnp.take(fac_refs[n][...], loc_refs[n][0, :], axis=0)
-        return rows[:, : rl * rr].astype(jnp.float32).reshape(blk, rl, rr)
-
-    # Left interface chain: row-vector times core matrix, ascending over the
-    # inputs left of the output mode — (blk, rl) -> (blk, rr) per step.
-    left = jnp.ones((blk, 1), jnp.float32)
+    With c = a * rr + b the column of an (rl, rr) interface row:
+      left step   l'[:, b] = sum_a l[:, a] W[:, c]  — spread l by c // rr,
+                  multiply, reduce by c % rr;
+      right step  r'[:, a] = sum_b W[:, c] r[:, b]  — spread r by c % rr,
+                  multiply, reduce by c // rr."""
+    blk = rows[0].shape[0]
+    cw = rank_padded(max(max(p) for p in in_rank_pairs))
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (blk, cw), 1)
+    unit = (lanes == 0).astype(jnp.float32)  # the width-1 chain start
+    left = right = unit
     for n in range(n_left):
-        left = jnp.sum(left[:, :, None] * gathered(n), axis=1)
-    # Right interface chain: core matrix times column-vector, descending over
-    # the inputs right of the output mode — (blk, rr) -> (blk, rl) per step.
-    right = jnp.ones((blk, 1), jnp.float32)
-    for n in range(n_in - 1, n_left - 1, -1):
-        right = jnp.sum(gathered(n) * right[:, None, :], axis=2)
-
-    # Kronecker of the two interfaces (rl_m slow, rr_m fast — the core-matrix
-    # column convention), scaled by the stream values.
-    contrib = vals[:, None].astype(jnp.float32) * (
-        left[:, :, None] * right[:, None, :]
-    ).reshape(blk, -1)
-
-    # Zero-pad the true rl_m*rr_m columns up to the output tile's lane width.
-    pp = out_ref.shape[1]
-    if contrib.shape[1] < pp:
-        contrib = jnp.concatenate(
-            [contrib, jnp.zeros((blk, pp - contrib.shape[1]), jnp.float32)], axis=1
-        )
-
-    # MXU segment accumulation: one-hot (tile_i, blk) @ contrib (blk, Pp).
-    rows_iota = jax.lax.broadcasted_iota(jnp.int32, (tile_i, blk), 0)
-    onehot = (rows_iota == il[None, :]).astype(jnp.float32)
-    out_ref[...] += jax.lax.dot(onehot, contrib, preferred_element_type=jnp.float32)
+        rl, rr = in_rank_pairs[n]
+        w = rows[n].shape[1]
+        prod = dot(left, spread(cw, w, width=rl * rr, stride=rr, count=rl)) * rows[n]
+        left = dot(prod, spread(w, cw, width=rl * rr, stride=1, count=rr, transpose=True))
+    for n in range(len(in_rank_pairs) - 1, n_left - 1, -1):
+        rl, rr = in_rank_pairs[n]
+        w = rows[n].shape[1]
+        prod = rows[n] * dot(right, spread(cw, w, width=rl * rr, stride=1, count=rr))
+        right = dot(prod, spread(w, cw, width=rl * rr, stride=rr, count=rl, transpose=True))
+    rl, rr = tt_out_pair(in_rank_pairs, n_left)
+    return (
+        dot(left, spread(cw, pp, width=rl * rr, stride=rr, count=rl))
+        * dot(right, spread(cw, pp, width=rl * rr, stride=1, count=rr))
+    )
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "tile_i", "in_tiles", "in_rank_pairs", "n_left", "blk", "out_rows",
-        "interpret",
+        "tile_i", "in_tiles", "in_rank_pairs", "n_left", "out_rows", "interpret",
     ),
 )
 def ttcore_pallas_call(
     block_it: jax.Array,  # (nblocks,) int32
     block_in: Sequence[jax.Array],  # N-1 x (nblocks,) int32 input tile ids
-    vals: jax.Array,  # (nblocks, blk)
-    iloc: jax.Array,  # (nblocks, blk) int32
-    in_locs: Sequence[jax.Array],  # N-1 x (nblocks, blk) int32
+    vals: jax.Array,  # (nblocks, 1, blk)
+    iloc: jax.Array,  # (nblocks, 1, blk) int32
+    in_locs: Sequence[jax.Array],  # N-1 x (nblocks, 1, blk) int32
     factors_pad: Sequence[jax.Array],  # N-1 x (rows_n, rank_padded(rl*rr))
     *,
     tile_i: int,
     in_tiles: tuple[int, ...],  # N-1 input tile sizes
     in_rank_pairs: tuple[tuple[int, int], ...],  # N-1 (rl, rr) bond pairs
     n_left: int,  # inputs left of the output mode (== the output mode)
-    blk: int,
     out_rows: int,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Returns (out_rows, rank_padded(rl_m*rr_m)) float32: the mode-m TT-ALS
     right-hand side B_m with columns row-major over (rl_m, rr_m).  Input
     interface matrices in plan.in_modes order (ascending), each lane-padded
     to its own rank_padded(rl_n*rr_n)."""
-    block_in = tuple(block_in)
-    in_locs = tuple(in_locs)
-    factors_pad = tuple(factors_pad)
     in_rank_pairs = tuple((int(a), int(b)) for a, b in in_rank_pairs)
-    n_in = len(in_tiles)
-    assert len(block_in) == len(in_locs) == len(factors_pad) == n_in
-    assert len(in_rank_pairs) == n_in
-    assert 0 <= n_left <= n_in
-    nblocks = vals.shape[0]
-    pp = rank_padded(tt_out_cols(in_rank_pairs, n_left))
-
-    def stream_spec():
-        return pl.BlockSpec((1, blk), lambda b, it, *ts: (b, 0))
-
-    def factor_spec(n):
-        return pl.BlockSpec(
-            (in_tiles[n], factors_pad[n].shape[1]),
-            lambda b, it, *ts, n=n: (ts[n][b], 0),
+    if len(in_rank_pairs) != len(in_tiles) or not 0 <= n_left <= len(in_tiles):
+        raise ValueError(
+            f"{len(in_rank_pairs)} bond pairs and n_left={n_left} for "
+            f"{len(in_tiles)} input modes"
         )
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1 + n_in,  # output tile ids + one stream per input
-        grid=(nblocks,),
-        in_specs=(
-            [stream_spec()]  # vals (DMA stream)
-            + [stream_spec()]  # iloc
-            + [stream_spec() for _ in range(n_in)]  # input local indices
-            + [factor_spec(n) for n in range(n_in)]  # interface tiles (cache)
-        ),
-        out_specs=pl.BlockSpec((tile_i, pp), lambda b, it, *ts: (it[b], 0)),
-    )
-    return pl.pallas_call(
-        functools.partial(_kernel, tile_i, n_in, in_rank_pairs, n_left),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((out_rows, pp), jnp.float32),
+    pp = rank_padded(tt_out_cols(in_rank_pairs, n_left))
+    return blocked_call(
+        functools.partial(_chain_contract, in_rank_pairs, n_left, pp),
+        block_it, block_in, vals, iloc, in_locs, factors_pad,
+        tile_i=tile_i, in_tiles=in_tiles, out_rows=out_rows, out_cols=pp,
         interpret=interpret,
-    )(block_it, *block_in, vals, iloc, *in_locs, *factors_pad)
+    )

@@ -9,25 +9,15 @@ restricted to X's non-zeros: every nnz z contributes
 value_z * kron(U^(m_1)[i_{m_1}, :], ..., U^(m_{N-2})[i_{m_{N-2}}, :]) to output
 row i_n.  That is MTTKRP with the per-element Hadamard product replaced by a
 Kronecker (outer) product of the gathered factor rows — the irregular memory
-access pattern is IDENTICAL, so the kernel reuses the exact BlockPlan layout
-(per-output-mode tile-id streams + local indices) the Tensor Remapper builds
-for MTTKRP.  Engine mapping is unchanged (see kernels/mttkrp_pallas.py):
+access pattern is IDENTICAL, so the kernel runs on the same scaffold
+(kernels/blocked.py) over the same BlockPlan layout.
 
-  * DMA Engine    — (nblocks, blk) BlockSpec stream tiles, double-buffered;
-  * Cache Engine  — one (tile_n x Rp_n) factor tile per input mode, selected
-                    by scalar-prefetched tile ids (copy skipped on repeats);
-  * Approach 1    — blocks sorted by output tile: the (tile_i x Pp) core-slice
-                    accumulator is resident across its run, flushed once;
-  * MXU           — segment accumulation as a one-hot matmul
-                    (tile_i x blk) @ (blk x Pp).
-
-Differences from the MTTKRP kernel: each input factor keeps its OWN rank
-R_m (lane-padded to rank_padded(R_m)); the kernel slices the true columns
-before the Kronecker chain, and the output carries P = prod(R_m) columns
-(lane-padded to cols_padded(P)) instead of R.
-
-Validated in interpret=True mode against kernels/ref.py (CPU container; TPU
-is the target).
+Each input factor keeps its OWN rank R_m (lane-padded to rank_padded(R_m));
+the output carries P = prod(R_m) columns (lane-padded to cols_padded(P)).
+The Kronecker product is built without reshapes: each input's gathered rows
+are spread into the output column layout by a 0/1 matmul (column c takes
+row entry (c // stride_m) % R_m, stride_m the product of the later ranks),
+and the spread rows are multiplied elementwise.
 """
 from __future__ import annotations
 
@@ -36,10 +26,8 @@ import math
 from typing import Sequence
 
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
+from .blocked import blocked_call, dot, spread
 from .mttkrp_pallas import rank_padded
 
 __all__ = ["ttmc_pallas_call", "cols_padded", "kron_cols"]
@@ -57,115 +45,43 @@ def kron_cols(in_ranks: Sequence[int]) -> int:
     return math.prod(int(r) for r in in_ranks)
 
 
-def _kernel(tile_i: int, n_in: int, in_ranks: tuple[int, ...], *refs):
-    """Template-unrolled kernel body for n_in input factor tiles.
-
-    refs layout is identical to the MTTKRP kernel (the plan layout is shared):
-      [0]                    it_ref           scalar-prefetch: output tile ids
-      [1 : 1+n_in]           input tile ids   (scalar-prefetch, unused in body)
-      [1+n_in]               vals_ref         (1, blk)
-      [2+n_in]               iloc_ref         (1, blk)
-      [3+n_in : 3+2*n_in]    input local idx  (1, blk) each
-      [3+2*n_in : 3+3*n_in]  factor tiles     (tile_n, Rp_n) each
-      [3+3*n_in]             out_ref          (tile_i, Pp)
-    """
-    it_ref = refs[0]
-    vals_ref = refs[1 + n_in]
-    iloc_ref = refs[2 + n_in]
-    loc_refs = refs[3 + n_in : 3 + 2 * n_in]
-    fac_refs = refs[3 + 2 * n_in : 3 + 3 * n_in]
-    out_ref = refs[3 + 3 * n_in]
-
-    b = pl.program_id(0)
-    # Approach-1 accumulator management: zero on the first block of each
-    # output tile's contiguous run (Tensor Remapper guarantees contiguity).
-    prev = jnp.maximum(b - 1, 0)
-    first_visit = jnp.logical_or(b == 0, it_ref[b] != it_ref[prev])
-
-    @pl.when(first_visit)
-    def _():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    vals = vals_ref[0, :]  # (blk,)
-    il = iloc_ref[0, :]
-    blk = vals.shape[0]
-
-    # Cache Engine gather + Kronecker chain: contrib grows from (blk, 1) to
-    # (blk, prod(in_ranks)) one input mode at a time; each gathered row set is
-    # sliced to its true rank so lane padding never enters the product.
-    contrib = vals[:, None].astype(jnp.float32)
-    for loc_ref, fac_ref, r in zip(loc_refs, fac_refs, in_ranks):
-        rows = jnp.take(fac_ref[...], loc_ref[0, :], axis=0)  # (blk, Rp_n)
-        rows = rows[:, :r].astype(jnp.float32)
-        contrib = (contrib[:, :, None] * rows[:, None, :]).reshape(blk, -1)
-
-    # Zero-pad the true P columns up to the output tile's lane width.
-    pp = out_ref.shape[1]
-    if contrib.shape[1] < pp:
-        contrib = jnp.concatenate(
-            [contrib, jnp.zeros((blk, pp - contrib.shape[1]), jnp.float32)], axis=1
-        )
-
-    # MXU segment accumulation: one-hot (tile_i, blk) @ contrib (blk, Pp).
-    rows_iota = jax.lax.broadcasted_iota(jnp.int32, (tile_i, blk), 0)
-    onehot = (rows_iota == il[None, :]).astype(jnp.float32)
-    out_ref[...] += jax.lax.dot(onehot, contrib, preferred_element_type=jnp.float32)
+def _kron_contract(in_ranks: tuple[int, ...], pp: int, rows: list) -> jax.Array:
+    """kron of the gathered rows (row-major over the inputs), (blk, pp)."""
+    p = kron_cols(in_ranks)
+    contrib = None
+    for n, (r, x) in enumerate(zip(in_ranks, rows)):
+        stride = kron_cols(in_ranks[n + 1 :])
+        e = spread(x.shape[1], pp, width=p, stride=stride, count=r)
+        term = dot(x, e)
+        contrib = term if contrib is None else contrib * term
+    return contrib
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("tile_i", "in_tiles", "in_ranks", "blk", "out_rows", "interpret"),
+    static_argnames=("tile_i", "in_tiles", "in_ranks", "out_rows", "interpret"),
 )
 def ttmc_pallas_call(
     block_it: jax.Array,  # (nblocks,) int32
     block_in: Sequence[jax.Array],  # N-1 x (nblocks,) int32 input tile ids
-    vals: jax.Array,  # (nblocks, blk)
-    iloc: jax.Array,  # (nblocks, blk) int32
-    in_locs: Sequence[jax.Array],  # N-1 x (nblocks, blk) int32
+    vals: jax.Array,  # (nblocks, 1, blk)
+    iloc: jax.Array,  # (nblocks, 1, blk) int32
+    in_locs: Sequence[jax.Array],  # N-1 x (nblocks, 1, blk) int32
     factors_pad: Sequence[jax.Array],  # N-1 x (rows_n, Rp_n), plan.in_modes order
     *,
     tile_i: int,
     in_tiles: tuple[int, ...],  # N-1 input tile sizes
     in_ranks: tuple[int, ...],  # N-1 true input-factor ranks
-    blk: int,
     out_rows: int,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Returns (out_rows, cols_padded(prod(in_ranks))) float32: the mode-n
     TTMc unfolding with row-major column order over plan.in_modes."""
-    block_in = tuple(block_in)
-    in_locs = tuple(in_locs)
-    factors_pad = tuple(factors_pad)
     in_ranks = tuple(int(r) for r in in_ranks)
-    n_in = len(in_tiles)
-    assert len(block_in) == len(in_locs) == len(factors_pad) == n_in
-    assert len(in_ranks) == n_in
-    nblocks = vals.shape[0]
     pp = cols_padded(kron_cols(in_ranks))
-
-    def stream_spec():
-        return pl.BlockSpec((1, blk), lambda b, it, *ts: (b, 0))
-
-    def factor_spec(n):
-        return pl.BlockSpec(
-            (in_tiles[n], factors_pad[n].shape[1]),
-            lambda b, it, *ts, n=n: (ts[n][b], 0),
-        )
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1 + n_in,  # output tile ids + one stream per input
-        grid=(nblocks,),
-        in_specs=(
-            [stream_spec()]  # vals (DMA stream)
-            + [stream_spec()]  # iloc
-            + [stream_spec() for _ in range(n_in)]  # input local indices
-            + [factor_spec(n) for n in range(n_in)]  # factor tiles (cache)
-        ),
-        out_specs=pl.BlockSpec((tile_i, pp), lambda b, it, *ts: (it[b], 0)),
-    )
-    return pl.pallas_call(
-        functools.partial(_kernel, tile_i, n_in, in_ranks),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((out_rows, pp), jnp.float32),
+    return blocked_call(
+        functools.partial(_kron_contract, in_ranks, pp),
+        block_it, block_in, vals, iloc, in_locs, factors_pad,
+        tile_i=tile_i, in_tiles=in_tiles, out_rows=out_rows, out_cols=pp,
         interpret=interpret,
-    )(block_it, *block_in, vals, iloc, *in_locs, *factors_pad)
+    )

@@ -16,8 +16,7 @@ and the factor-update math.  This module owns the shared layer:
   * the lazily-compiled sweep cache (`sweep` builds `_build_sweep()` once);
   * `drive` — the host loop shared by every jitted path: pad once, one sweep
     per iteration, host-side tol early-exit, unpad at materialization;
-  * visited-row masking (`_apply_row_mask` / `_visited_row_mask`) and the
-    device-side plan arrays every kernel family consumes.
+  * the device-side plan arrays every kernel family consumes.
 
 Format classes (`PlannedCPALS`, `PlannedTucker`, `PlannedTT` and their
 sharded variants) subclass `PlannedWorkspace` / `ShardedWorkspace` and
@@ -98,41 +97,18 @@ def _jitter_factors(factors, attempt: int):
     return out
 
 
-def _apply_row_mask(out: jax.Array, mask: jax.Array) -> jax.Array:
-    """Zero the masked-out rows with `where`, NOT multiplication: unvisited
-    tiles hold NaN in interpret mode and 0 * NaN = NaN."""
-    return jnp.where(mask[:, None] > 0, out, 0.0)
-
-
-def _visited_row_mask(block_it: np.ndarray, tile_i: int, out_rows: int) -> np.ndarray:
-    """1.0 for every output row whose tile some block visits, else 0.0.
-
-    The Pallas kernels zero an output tile only on its *first visit*; a tile
-    no block targets keeps whatever the output buffer held (NaN in interpret
-    mode, undefined on hardware).  Such tiles exist whenever a tile_i range
-    of the output coordinate owns no non-zeros — their MTTKRP/TTMc/TT-core
-    rows are mathematically zero, so every planned call multiplies by this
-    mask."""
-    ntiles = out_rows // tile_i
-    tile_mask = np.zeros((ntiles,), np.float32)
-    tile_mask[np.unique(block_it)] = 1.0
-    return np.repeat(tile_mask, tile_i)
-
-
-def _plan_device_arrays(plan: BlockPlan) -> dict:
-    """Move a BlockPlan's layout to device in the shape the kernels consume:
-    (nblocks, blk) stream tiles + per-block tile-id streams + the
-    visited-row mask zeroing tiles the plan never touches."""
+def _plan_device_arrays(plan: BlockPlan) -> tuple:
+    """Move a BlockPlan's layout to device as the kernels' leading arguments:
+    the per-block tile-id streams, then the (nblocks, 1, blk) stream arrays
+    (values, output local indices, input local indices)."""
     nb, blk = plan.nblocks, plan.blk
-    return dict(
-        block_it=jnp.asarray(plan.block_it),
-        block_in=tuple(jnp.asarray(t) for t in plan.block_in),
-        vals=jnp.asarray(plan.vals).reshape(nb, blk),
-        iloc=jnp.asarray(plan.iloc).reshape(nb, blk),
-        in_locs=tuple(jnp.asarray(l).reshape(nb, blk) for l in plan.in_locs),
-        row_mask=jnp.asarray(
-            _visited_row_mask(plan.block_it, plan.tile_i, plan.out_rows)
-        ),
+    stream = lambda a: jnp.asarray(a).reshape(nb, 1, blk)
+    return (
+        jnp.asarray(plan.block_it),
+        tuple(jnp.asarray(t) for t in plan.block_in),
+        stream(plan.vals),
+        stream(plan.iloc),
+        tuple(stream(l) for l in plan.in_locs),
     )
 
 
@@ -259,9 +235,24 @@ class PlannedWorkspace:
         factors straight into the next call incurs zero host transfers and
         zero re-padding.  The compiled sweep is built lazily on first use and
         cached for the workspace's lifetime."""
+        return self._jitted_sweep()(*self._sweep_operands(facs, args), **kwargs)
+
+    def lower_sweep(self, facs, *args, **kwargs) -> jax.stages.Lowered:
+        """The sweep lowered for these operands (the `sweep` contract).
+        `.compile()` gives the program `sweep` runs: its compile time and
+        its HLO text, where a compiled Pallas kernel is a `tpu_custom_call`."""
+        return self._jitted_sweep().lower(*self._sweep_operands(facs, args), **kwargs)
+
+    def _jitted_sweep(self):
         if self._sweep_fn is None:
             self._sweep_fn = self._build_sweep()
-        return self._sweep_fn(facs, *args, **kwargs)
+        return self._sweep_fn
+
+    def _sweep_operands(self, facs, args) -> tuple:
+        """The per-mode layouts lead the sweep's operands: arrays a jitted
+        function closes over are embedded in its program as constants,
+        which at a real tensor's size is gigabytes of program."""
+        return ({m: op.layout for m, op in self.ops.items()}, facs, *args)
 
     def _sweep_call(self, facs, *args, it: int):
         """`drive`'s per-iteration hook; formats whose sweep takes the
@@ -490,11 +481,8 @@ class ShardedWorkspace(PlannedWorkspace):
     def _stream_args(self) -> tuple:
         return ()
 
-    def sweep(self, facs, *args, **kwargs):
-        """One jitted distributed iteration in padded space — the
-        `PlannedWorkspace.sweep` contract minus any stream arguments (each
-        shard's slice already lives on its device)."""
-        if self._sweep_fn is None:
-            self._sweep_fn = self._build_sweep()
+    def _sweep_operands(self, facs, args) -> tuple:
+        """The `PlannedWorkspace.sweep` contract minus any stream arguments:
+        each shard's slice already lives on its device."""
         arrs = {m: self.stacks[m].tree() for m in range(self.nmodes)}
-        return self._sweep_fn(arrs, *self._stream_args(), facs, *args, **kwargs)
+        return (arrs, *self._stream_args(), facs, *args)

@@ -59,6 +59,7 @@ from ..core.loop import (
     check_drive_extras,
     check_planned_method,
     check_workspace,
+    f32_matmuls,
     finish_iter,
     require_sharded_sweep,
 )
@@ -340,7 +341,7 @@ class PlannedTT(PlannedWorkspace):
         rps, prows = self.rank_pads, self.padded_rows
         ops = self.ops
 
-        def sweep(facs, idx, val, norm_x_sq):
+        def sweep(layouts, facs, idx, val, norm_x_sq):
             facs = list(facs)
             cores = [
                 matrix_to_core(facs[m][: shape[m], : lr[m]], *pairs[m])
@@ -355,7 +356,7 @@ class PlannedTT(PlannedWorkspace):
                 in_mats = tuple(
                     facs[im][: pln.in_rows[n]] for n, im in enumerate(pln.in_modes)
                 )
-                out = op.call_padded(in_mats)
+                out = op.call_padded(in_mats, layouts[m])
                 b = out[: shape[m], : lr[m]]
                 w = _solve_core(jnp.kron(p, qs[m]), b)
                 cores[m] = matrix_to_core(w, *pairs[m])
@@ -390,7 +391,7 @@ class PlannedTT(PlannedWorkspace):
             op.cfg.vmem_bytes_tt(
                 rank_padded(op.out_pair[0] * op.out_pair[1]),
                 tuple(rank_padded(a * b) for a, b in op.in_rank_pairs),
-                _tt_iface_cols(op.in_rank_pairs, op.n_left),
+                _tt_iface_cols(op.in_rank_pairs),
             )
             for op in self.ops.values()
         )
@@ -449,7 +450,6 @@ def make_planned_tt(
     cfg: MemoryControllerConfig | None = None,
     auto_tune: bool | str = False,
     spec: TPUSpec | str = TPUSpec(),
-    interpret: bool = True,
 ) -> PlannedTT:
     """Build the full TT-ALS workspace: one tuned TT-core plan per output
     mode.
@@ -461,13 +461,14 @@ def make_planned_tt(
     tr = _validated_tt_ranks(st, tt_ranks)
     ops = {
         m: make_planned_ttcore(
-            st, m, tr, cfg=cfg, auto_tune=auto_tune, spec=spec, interpret=interpret
+            st, m, tr, cfg=cfg, auto_tune=auto_tune, spec=spec
         )
         for m in range(st.nmodes)
     }
     return PlannedTT(ops=ops, shape=st.shape, tt_ranks=tr)
 
 
+@f32_matmuls
 def tt_als(
     st: SparseTensor,
     tt_ranks: int | Sequence[int],
@@ -478,7 +479,6 @@ def tt_als(
     seed: int = 0,
     tol: float | None = None,
     planned: "PlannedTT | None" = None,
-    interpret: bool = True,
     auto_tune: bool | str = False,
     spec: TPUSpec | str = "default",
     cfg: MemoryControllerConfig | None = None,
@@ -504,7 +504,7 @@ def tt_als(
     init:   'svd' — deterministic TT-SVD warm start (densifies; guarded to
             2^22 elements); 'random' — left-orthogonal random cores from
             `seed`; 'auto' — SVD when the dense guard allows, else random.
-    planned / interpret / auto_tune / cfg: pallas-path knobs — pass a
+    planned / auto_tune / cfg: pallas-path knobs — pass a
             prebuilt `PlannedTT` (or `ShardedPlannedTT`) to reuse plans
             across calls, or let auto_tune run the TT-aware PMS per mode
             (worst-shard makespan for the sharded path).
@@ -546,7 +546,7 @@ def tt_als(
         if planned is None:
             planned = make_sharded_planned_tt(
                 st, tr, dist=dist, devices=devices, cfg=cfg,
-                auto_tune=auto_tune, spec=spec, interpret=interpret,
+                auto_tune=auto_tune, spec=spec,
             )
         else:
             check_workspace(
@@ -567,7 +567,6 @@ def tt_als(
         if planned is None:
             planned = make_planned_tt(
                 st, tr, cfg=cfg, auto_tune=auto_tune, spec=spec,
-                interpret=interpret,
             )
         else:
             check_workspace(

@@ -47,6 +47,7 @@ from ..core.loop import (
     check_drive_extras,
     check_planned_method,
     check_workspace,
+    f32_matmuls,
     finish_iter,
     require_sharded_sweep,
 )
@@ -199,7 +200,7 @@ class PlannedTucker(PlannedWorkspace):
         rps, prows = self.rank_pads, self.padded_rows
         ops = self.ops
 
-        def sweep(facs, norm_x_sq):
+        def sweep(layouts, facs, norm_x_sq):
             facs = list(facs)
             y = None
             for m in range(nmodes):
@@ -207,7 +208,7 @@ class PlannedTucker(PlannedWorkspace):
                 in_facs = tuple(
                     facs[im][: p.in_rows[n]] for n, im in enumerate(p.in_modes)
                 )
-                out = op.call_padded(in_facs)
+                out = op.call_padded(in_facs, layouts[m])
                 y = out[: shape[m], : op.out_cols]
                 u = _factor_from_unfolding(y, core_ranks[m])
                 # Re-pad in place of the old padded factor (padding rows and
@@ -260,7 +261,7 @@ class PlannedTucker(PlannedWorkspace):
         shape, core_ranks, nmodes = self.shape, self.core_ranks, self.nmodes
         rps, prows = self.rank_pads, self.padded_rows
 
-        def sweep(facs, norm_x_sq):
+        def sweep(idx, val, facs, norm_x_sq):
             facs = list(facs)
             y = None
             for m in range(nmodes):
@@ -278,7 +279,7 @@ class PlannedTucker(PlannedWorkspace):
             return tuple(facs), core, core_fit_value(core, norm_x_sq)
 
         jitted = jax.jit(sweep)
-        return lambda facs, *args, it: jitted(facs, *args)
+        return lambda facs, *args, it: jitted(idx, val, facs, *args)
 
 
 def make_planned_tucker(
@@ -288,7 +289,6 @@ def make_planned_tucker(
     cfg: MemoryControllerConfig | None = None,
     auto_tune: bool | str = False,
     spec: TPUSpec | str = TPUSpec(),
-    interpret: bool = True,
 ) -> PlannedTucker:
     """Build the full HOOI workspace: one tuned TTMc plan per output mode.
 
@@ -298,13 +298,14 @@ def make_planned_tucker(
     cr = _validated_core_ranks(st, core_ranks)
     ops = {
         m: make_planned_ttmc(
-            st, m, cr, cfg=cfg, auto_tune=auto_tune, spec=spec, interpret=interpret
+            st, m, cr, cfg=cfg, auto_tune=auto_tune, spec=spec
         )
         for m in range(st.nmodes)
     }
     return PlannedTucker(ops=ops, shape=st.shape, core_ranks=cr)
 
 
+@f32_matmuls
 def tucker_hooi(
     st: SparseTensor,
     core_ranks: Sequence[int],
@@ -314,7 +315,6 @@ def tucker_hooi(
     seed: int = 0,
     tol: float | None = None,
     planned: "PlannedTucker | None" = None,
-    interpret: bool = True,
     auto_tune: bool | str = False,
     spec: TPUSpec | str = "default",
     cfg: MemoryControllerConfig | None = None,
@@ -336,7 +336,7 @@ def tucker_hooi(
             shard-local layouts, one jitted shard_map sweep per iteration
             with a single psum of the partial TTMc unfolding per mode;
             'reference' — the pure-jnp TTMc oracle.
-    planned / interpret / auto_tune / cfg: pallas-path knobs — pass a
+    planned / auto_tune / cfg: pallas-path knobs — pass a
             prebuilt `PlannedTucker` (or `ShardedPlannedTucker`) to reuse
             plans across calls, or let auto_tune run the TTMc-aware PMS per
             mode (worst-shard makespan for the sharded path).
@@ -369,7 +369,7 @@ def tucker_hooi(
         if planned is None:
             planned = make_sharded_planned_tucker(
                 st, cr, dist=dist, devices=devices, cfg=cfg,
-                auto_tune=auto_tune, spec=spec, interpret=interpret,
+                auto_tune=auto_tune, spec=spec,
             )
         else:
             check_workspace(
@@ -386,7 +386,6 @@ def tucker_hooi(
         if planned is None:
             planned = make_planned_tucker(
                 st, cr, cfg=cfg, auto_tune=auto_tune, spec=spec,
-                interpret=interpret,
             )
         else:
             check_workspace(

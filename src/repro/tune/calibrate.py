@@ -185,7 +185,7 @@ def _cfg_label(cfg: MemoryControllerConfig) -> str:
 
 def sweep_sample(
     st, rank: int, cfg: MemoryControllerConfig, *, reps: int = 2,
-    interpret: bool = True, seed: int = 0,
+    seed: int = 0,
 ) -> CalibSample:
     """Build the planned CP-ALS workspace at `cfg`, time its steady-state
     jitted sweep (one compile + one warm call, then best of `reps`), and
@@ -196,7 +196,7 @@ def sweep_sample(
     from ..core.coo import random_factors
     from ..kernels.ops import make_planned_cp_als
 
-    ws = make_planned_cp_als(st, rank, cfg=cfg, interpret=interpret)
+    ws = make_planned_cp_als(st, rank, cfg=cfg)
     per_mode = roofline_counts(ws)
     facs = ws.pad_factors(random_factors(jax.random.PRNGKey(seed), st.shape, rank))
     idx, val = jnp.asarray(st.indices), jnp.asarray(st.values)
@@ -334,7 +334,6 @@ def calibrate(
     reps: int = 2,
     base: TPUSpec = TPUSpec(),
     microbench: bool = True,
-    interpret: bool = True,
     seed: int = 0,
 ) -> CalibrationResult:
     """Run the full calibration workflow on the default backend: (optional)
@@ -349,7 +348,7 @@ def calibrate(
         pf = measure_peak_flops_f32() if microbench else None
         st = frostt_like(preset)
         samples = tuple(
-            sweep_sample(st, rank, cfg, reps=reps, interpret=interpret, seed=seed)
+            sweep_sample(st, rank, cfg, reps=reps, seed=seed)
             for cfg in cfgs
         )
         fitted = fit_spec(
@@ -397,7 +396,8 @@ def resolve_spec(
     """Resolve the `spec=` argument every PMS entry point accepts:
 
       * a `TPUSpec` passes through;
-      * ``"default"`` is the datasheet `TPUSpec()`;
+      * ``"default"`` is the published constants of the chip this runs on
+        (`repro.platform.device_spec`);
       * ``"measured"`` is this backend's fitted spec from the autotune
         cache — on a cache miss, a quick calibration runs and persists
         (`QUICK_CALIBRATION_KWARGS`) when `calibrate_on_miss` is set,
@@ -406,7 +406,9 @@ def resolve_spec(
     if isinstance(spec, TPUSpec):
         return spec
     if spec == "default":
-        return TPUSpec()
+        from ..platform import device_spec
+
+        return device_spec()
     if spec != "measured":
         raise ValueError(
             f"unknown spec {spec!r}: expected a TPUSpec, 'default' or 'measured'"

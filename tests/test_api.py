@@ -38,24 +38,25 @@ def test_decompose_matches_legacy_drivers(nnz, base, extra, rank, seed):
     """Property (stub-compatible): on 3/4/5-mode tensors, the facade's fit
     history equals the legacy `cp_als` / `tucker_hooi` / `tt_als` histories
     BIT FOR BIT — `decompose` holds no algorithm logic, it only normalizes
-    the rank and dispatches."""
+    the rank and dispatches.  A degenerate tensor (one nonzero) can drive
+    both to the same NaN fit, which counts as equal."""
     dims = base + extra
     st_t = synthetic_tensor(dims, nnz, seed=seed, skew=0.5)
     # CP: 'approach1' is the eager compute-pattern baseline (CP's oracle role)
     a = decompose(st_t, rank, format="cp", method="approach1", iters=2, seed=seed)
     b = cp_als(st_t, rank, method="approach1", iters=2, seed=seed)
-    assert a.fit_history == b.fit_history
+    np.testing.assert_array_equal(a.fit_history, b.fit_history)
     # Tucker: the pure-jnp reference
     tr = tuple(min(rank, 3) for _ in dims)
     a = decompose(st_t, tr, format="tucker", method="reference", iters=2, seed=seed)
     b = tucker_hooi(st_t, tr, method="reference", iters=2, seed=seed)
-    assert a.fit_history == b.fit_history
+    np.testing.assert_array_equal(a.fit_history, b.fit_history)
     # TT: the pure-jnp reference, random init keyed by the same seed
     bond = (min(rank, 3),) * (len(dims) - 1)
     a = decompose(st_t, bond, format="tt", method="reference", iters=2,
                   seed=seed, init="random")
     b = tt_als(st_t, bond, method="reference", iters=2, seed=seed, init="random")
-    assert a.fit_history == b.fit_history
+    np.testing.assert_array_equal(a.fit_history, b.fit_history)
 
 
 def test_decompose_pallas_matches_legacy(tiny_tensor):

@@ -59,7 +59,7 @@ def test_pallas_backed_cp_als():
     """CP-ALS with the Pallas kernel (interpret mode) as the MTTKRP engine."""
     st_t = low_rank_tensor(shape=(16, 12, 20), seed=5)
 
-    ops = {m: make_planned_mttkrp(st_t.sorted_by(m), m, 4, interpret=True) for m in range(3)}
+    ops = {m: make_planned_mttkrp(st_t.sorted_by(m), m, 4) for m in range(3)}
 
     def mttkrp_fn(indices, values, factors, mode, out_rows):
         return ops[mode].output(factors, out_rows)
@@ -97,7 +97,7 @@ def test_planned_cp_als_plans_built_once(monkeypatch):
     cp_als(st_t, rank=4, iters=4, method="pallas", seed=0)
     assert len(calls) == st_t.nmodes
 
-    planned = make_planned_cp_als(st_t, 4, interpret=True)
+    planned = make_planned_cp_als(st_t, 4)
     calls.clear()
     s = cp_als(st_t, rank=4, iters=2, method="pallas", planned=planned, seed=0)
     assert calls == []

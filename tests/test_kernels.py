@@ -1,4 +1,4 @@
-"""Pallas MTTKRP kernel: interpret-mode validation against the pure-jnp
+"""Pallas MTTKRP kernel: validation against the pure-jnp
 oracles across shapes, dtypes, and memory-controller configurations."""
 import jax
 import jax.numpy as jnp
@@ -26,7 +26,7 @@ def _totals(stats: dict) -> tuple[int, int]:
 
 def _check(st_t, mode, rank, cfg=None, rtol=2e-4):
     facs = random_factors(jax.random.PRNGKey(0), st_t.shape, rank)
-    out = mttkrp_auto(st_t, facs, mode, method="pallas", interpret=True, cfg=cfg)
+    out = mttkrp_auto(st_t, facs, mode, method="pallas", cfg=cfg)
     ref = mttkrp_ref(
         jnp.asarray(st_t.indices), jnp.asarray(st_t.values), facs, mode, st_t.shape[mode]
     )
@@ -61,7 +61,7 @@ def test_kernel_controller_config_sweep(tiny_tensor, tiles):
 
 def test_kernel_bf16_inputs(tiny_tensor):
     facs = [f.astype(jnp.bfloat16) for f in random_factors(jax.random.PRNGKey(0), tiny_tensor.shape, 16)]
-    op = make_planned_mttkrp(tiny_tensor, 0, 16, interpret=True)
+    op = make_planned_mttkrp(tiny_tensor, 0, 16)
     out = op.output(facs, tiny_tensor.shape[0])
     ref = mttkrp_ref(
         jnp.asarray(tiny_tensor.indices),
@@ -88,12 +88,12 @@ def test_kernel_vs_plan_ref(tiny_tensor):
     out = mttkrp_pallas_call(
         jnp.asarray(plan.block_it),
         tuple(jnp.asarray(t) for t in plan.block_in),
-        jnp.asarray(plan.vals).reshape(nb, plan.blk),
-        jnp.asarray(plan.iloc).reshape(nb, plan.blk),
-        tuple(jnp.asarray(l).reshape(nb, plan.blk) for l in plan.in_locs),
+        jnp.asarray(plan.vals).reshape(nb, 1, plan.blk),
+        jnp.asarray(plan.iloc).reshape(nb, 1, plan.blk),
+        tuple(jnp.asarray(l).reshape(nb, 1, plan.blk) for l in plan.in_locs),
         pads,
-        tile_i=plan.tile_i, in_tiles=plan.in_tiles,
-        blk=plan.blk, out_rows=plan.out_rows, interpret=True,
+        tile_i=plan.tile_i, in_tiles=plan.in_tiles, out_rows=plan.out_rows,
+        interpret=True,
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-4, atol=1e-4)
 
@@ -132,7 +132,6 @@ def test_kernel_higher_order_vs_plan_ref(request, fixture, mode):
             cache=CacheEngineConfig(tile_i=16, tile_j=16, tile_k=16),
             dma=DMAEngineConfig(blk=32),
         ),
-        interpret=True,
     )
     out = op.output(facs, st_t.shape[mode])
     np.testing.assert_allclose(

@@ -26,7 +26,13 @@ def test_vmem_model_counts_all_engines():
         dma=DMAEngineConfig(blk=256, buffers=2),
     )
     rp = 128
-    want = 2 * ((256 + 512 + 128) * rp * 4 + 256 * (4 + 12))
+    want = (
+        # double-buffered: accumulator carried in and written out, the
+        # factor tiles, and four (1, blk) stream rows padded to 8 sublanes
+        2 * ((2 * 256 * rp + (512 + 128) * rp) * 4 + 8 * 256 * (4 + 12))
+        + 256 * (512 + 256) * 4  # one-hot gather and segment matrices
+        + 256 * 2 * rp * 4  # gathered rows and their running product
+    )
     assert cfg.vmem_bytes(rp) == want
 
 
@@ -90,7 +96,11 @@ def test_vmem_model_ttmc_counts_core_tile():
         dma=DMAEngineConfig(blk=256, buffers=2),
     )
     pp, in_rps = 256, (128, 128)
-    want = 2 * ((256 * 256 + (512 + 128) * 128) * 4 + 256 * (4 + 12))
+    want = (
+        2 * ((2 * 256 * pp + (512 + 128) * 128) * 4 + 8 * 256 * (4 + 12))
+        + 256 * (512 + 256) * 4
+        + 256 * (128 + 2 * pp) * 4  # widest gathered rows, spread, product
+    )
     assert cfg.vmem_bytes_ttmc(pp, in_rps) == want
     # the kron widening makes TTMc strictly hungrier than MTTKRP at equal rank
     assert cfg.vmem_bytes_ttmc(256, (128, 128)) > cfg.vmem_bytes(128)

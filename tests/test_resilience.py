@@ -229,7 +229,7 @@ def test_cache_hit_revalidates_resident_plan(tiny_tensor, monkeypatch):
     """REPRO_VALIDATE_PLANS=1 must catch a plan corrupted AFTER it entered
     the cache — the hit path revalidates, not just the build path."""
     ops.plan_cache_clear()
-    args = ("mttkrp", tiny_tensor, 0, 8, None, True)
+    args = ("mttkrp", tiny_tensor, 0, 8, None)
     op = ops._planned_cached(
         *args, lambda: ops.make_planned_mttkrp(tiny_tensor, 0, 8)
     )
@@ -257,7 +257,7 @@ def test_plan_cache_churn_is_bounded(tiny_tensor):
         ops.plan_cache_config(4)
         for mode in range(10):  # 10 distinct keys through a 4-entry cache
             ops._planned_cached(
-                "mttkrp", tiny_tensor, mode, 8, None, True, lambda: object()
+                "mttkrp", tiny_tensor, mode, 8, None, lambda: object()
             )
         stats = ops.plan_cache_stats()
         assert stats["size"] <= 4
@@ -274,7 +274,7 @@ def test_plan_cache_config_evicts_down(tiny_tensor):
     try:
         for mode in range(6):
             ops._planned_cached(
-                "mttkrp", tiny_tensor, mode, 8, None, True, lambda: object()
+                "mttkrp", tiny_tensor, mode, 8, None, lambda: object()
             )
         ops.plan_cache_config(2)
         assert ops.plan_cache_stats()["size"] <= 2

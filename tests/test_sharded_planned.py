@@ -179,9 +179,9 @@ def test_sharded_tucker_on_one_device_matches_pallas(tiny_tensor):
 
 def test_empty_intra_range_tiles_are_zero_not_nan():
     """Regression: an output tile with NO non-zeros inside a plan's range is
-    never visited by the kernel, so its rows keep the uninitialized output
-    buffer (NaN in interpret mode) unless masked.  Both the single-device
-    planned path and the sharded path must return exact zeros there."""
+    never visited by the kernel.  Both the single-device planned path and
+    the sharded path must return exact zeros there, not whatever the output
+    buffer held."""
     import jax
 
     from repro.core.coo import SparseTensor, random_factors

@@ -107,7 +107,7 @@ def test_ttcore_pallas_all_modes(tiny_tensor, mode):
     tt_ranks = (3, 5)
     cores = random_cores(tiny_tensor.shape, tt_ranks, seed=7)
     op = make_planned_ttcore(
-        tiny_tensor, mode, tt_ranks, cfg=SMALL_CFG, interpret=True
+        tiny_tensor, mode, tt_ranks, cfg=SMALL_CFG
     )
     mats = [core_to_matrix(c) for c in cores]
     out = op.output(mats, tiny_tensor.shape[mode])
@@ -127,7 +127,7 @@ def test_ttcore_pallas_4d(tensor4d):
     cores = random_cores(tensor4d.shape, tt_ranks, seed=9)
     for mode in (0, 2, 3):
         op = make_planned_ttcore(
-            tensor4d, mode, tt_ranks, cfg=SMALL_CFG, interpret=True
+            tensor4d, mode, tt_ranks, cfg=SMALL_CFG
         )
         out = op.output([core_to_matrix(c) for c in cores], tensor4d.shape[mode])
         ref = ttcore_ref(
@@ -147,7 +147,7 @@ def test_ttcore_plan_ref_matches_pallas(tiny_tensor):
     in padded space (same gather order, same segment reduction)."""
     tt_ranks = (4, 3)
     cores = random_cores(tiny_tensor.shape, tt_ranks, seed=3)
-    op = make_planned_ttcore(tiny_tensor, 1, tt_ranks, cfg=SMALL_CFG, interpret=True)
+    op = make_planned_ttcore(tiny_tensor, 1, tt_ranks, cfg=SMALL_CFG)
     p = op.plan
     pads = tuple(
         pad_factor(core_to_matrix(cores[im]), rows, rank_padded(a * b))
@@ -308,7 +308,7 @@ def test_tt_als_monotone_and_tol_exit(tiny_tensor):
 def test_tt_als_workspace_reuse_and_validation(tiny_tensor):
     """A prebuilt PlannedTT is reused across calls; mismatched geometry or
     class is rejected by the shared check_workspace contract."""
-    planned = make_planned_tt(tiny_tensor, (3, 3), cfg=SMALL_CFG, interpret=True)
+    planned = make_planned_tt(tiny_tensor, (3, 3), cfg=SMALL_CFG)
     assert isinstance(planned, PlannedTT)
     assert planned.plan_bytes() > 0
     a = tt_als(tiny_tensor, (3, 3), iters=2, init="random", planned=planned)

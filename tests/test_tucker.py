@@ -74,7 +74,7 @@ def test_ttmc_pallas_all_modes(tiny_tensor, mode):
     """The planned Pallas TTMc kernel (interpret mode) == the jnp oracle on
     every output mode of the shared BlockPlan layout."""
     facs = random_factors(jax.random.PRNGKey(0), tiny_tensor.shape, 4)
-    out = tucker_auto(tiny_tensor, facs, mode, method="pallas", interpret=True)
+    out = tucker_auto(tiny_tensor, facs, mode, method="pallas")
     ref = tucker_auto(tiny_tensor, facs, mode, method="reference")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-4)
 
@@ -89,7 +89,7 @@ def test_ttmc_pallas_mixed_ranks(tiny_tensor):
         for k, s, r in zip(jax.random.split(rng, 3), tiny_tensor.shape, ranks)
     ]
     for mode in range(3):
-        out = tucker_auto(tiny_tensor, facs, mode, method="pallas", interpret=True)
+        out = tucker_auto(tiny_tensor, facs, mode, method="pallas")
         ref = tucker_auto(tiny_tensor, facs, mode, method="reference")
         assert out.shape[1] == kron_cols([r for m, r in enumerate(ranks) if m != mode])
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-4)
@@ -104,7 +104,7 @@ def test_ttmc_pallas_vs_plan_ref_higher_order(request, fixture):
         cache=CacheEngineConfig(tile_i=16, tile_j=16, tile_k=16),
         dma=DMAEngineConfig(blk=32),
     )
-    op = make_planned_ttmc(st_t, mode, (3,) * st_t.nmodes, cfg=cfg, interpret=True)
+    op = make_planned_ttmc(st_t, mode, (3,) * st_t.nmodes, cfg=cfg)
     plan = op.plan
     facs = random_factors(jax.random.PRNGKey(6), st_t.shape, 3)
     pads = tuple(
@@ -199,7 +199,7 @@ def test_hooi_validates_core_ranks(tiny_tensor):
     with pytest.raises(ValueError, match="full row rank"):
         # 9 > 2*2: the mode-0 unfolding of the core would be rank-deficient
         tucker_hooi(tiny_tensor, (9, 2, 2), iters=1)
-    ws = make_planned_tucker(tiny_tensor, (4, 4, 4), interpret=True)
+    ws = make_planned_tucker(tiny_tensor, (4, 4, 4))
     with pytest.raises(ValueError, match="workspace"):
         tucker_hooi(tiny_tensor, (3, 3, 3), iters=1, method="pallas", planned=ws)
     with pytest.raises(ValueError, match="ignored"):
@@ -227,7 +227,7 @@ def test_planned_tucker_plans_built_once(monkeypatch):
     tucker_hooi(st_t, (4, 4, 4), iters=4, method="pallas", seed=0)
     assert len(calls) == st_t.nmodes
 
-    planned = make_planned_tucker(st_t, (4, 4, 4), interpret=True)
+    planned = make_planned_tucker(st_t, (4, 4, 4))
     calls.clear()
     s = tucker_hooi(st_t, (4, 4, 4), iters=2, method="pallas", planned=planned, seed=0)
     assert calls == []
@@ -235,7 +235,7 @@ def test_planned_tucker_plans_built_once(monkeypatch):
 
 
 def test_planned_tucker_plan_bytes_and_padded_rows(tiny_tensor):
-    ws = make_planned_tucker(tiny_tensor, (4, 4, 4), interpret=True)
+    ws = make_planned_tucker(tiny_tensor, (4, 4, 4))
     assert ws.plan_bytes() > 0
     prows = ws.padded_rows
     assert all(
