@@ -33,6 +33,7 @@ from .loop import (
     finish_iter,
     require_sharded_sweep,
 )
+from ..obs import trace as _trace
 from .mttkrp import mttkrp, hadamard_rows
 from .remap import remap_stable
 
@@ -226,10 +227,11 @@ def cp_als(
     if layout not in ("remap", "copies"):
         raise ValueError(f"unknown layout {layout!r}: expected 'remap' or 'copies'")
     nmodes = st.nmodes
-    key = jax.random.PRNGKey(seed)
-    factors = random_factors(key, st.shape, rank)
-    lam = jnp.ones((rank,), jnp.float32)
-    norm_x_sq = jnp.asarray(float(np.sum(st.values.astype(np.float64) ** 2)), jnp.float32)
+    with _trace.span("job.init"):
+        factors = random_factors(jax.random.PRNGKey(seed), st.shape, rank)
+        lam = jnp.ones((rank,), jnp.float32)
+        norm_x_sq = jnp.asarray(
+            float(np.sum(st.values.astype(np.float64) ** 2)), jnp.float32)
     fits: list[float] = []
 
     check_planned_method(method, planned, devices, dist)
@@ -274,7 +276,8 @@ def cp_als(
         if jit_sweep:
             # Fast path: factors padded once, updated in padded space by one
             # jitted sweep per iteration; sliced back only for the CPState.
-            base_idx, base_val = jnp.asarray(st.indices), jnp.asarray(st.values)
+            with _trace.span("job.upload"):
+                base_idx, base_val = jnp.asarray(st.indices), jnp.asarray(st.values)
             factors, lam, fits = planned.drive(
                 factors, (base_idx, base_val, norm_x_sq), iters=iters, tol=tol,
                 verbose=verbose, label="cp_als", guards=guards,

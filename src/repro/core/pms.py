@@ -45,6 +45,8 @@ __all__ = [
     "search",
     "search_sharded",
     "DEFAULT_TILE_CHOICES",
+    "kernel_fetch_bytes",
+    "tile_bytes",
 ]
 
 
@@ -103,6 +105,55 @@ def _count_configs(kernel: str, n: int, *, sharded: bool = False) -> None:
     ).inc()
 
 
+def tile_bytes(
+    nblocks: int,
+    fills: dict[str, int],
+    *,
+    blk: int,
+    tile_i: int,
+    in_tiles: Sequence[int],
+    in_lanes: Sequence[int],
+    out_lanes: int,
+    remapper: RemapperConfig,
+) -> tuple[int, int, int]:
+    """HBM bytes of a kernel's walk over a layout, as (stream, input-factor
+    tiles, accumulator tile): every grid step streams one block (a value and
+    one local index per mode for each of `blk` slots, element widths from the
+    Remapper configuration), each fill of input mode n moves a
+    (in_tiles[n], in_lanes[n]) factor tile and each fill of "A" a
+    (tile_i, out_lanes) accumulator tile.  `fills` are
+    `BlockPlan.tile_fills` counts; the lane widths are the ones the kernel
+    pads to."""
+    r = remapper
+    stream = nblocks * blk * (r.value_bytes + (len(in_tiles) + 1) * r.index_bytes)
+    factor = sum(
+        fills[chr(ord("B") + n)] * t * w
+        for n, (t, w) in enumerate(zip(in_tiles, in_lanes))
+    ) * r.value_bytes
+    out = fills["A"] * tile_i * out_lanes * r.value_bytes
+    return stream, factor, out
+
+
+def kernel_fetch_bytes(
+    plan: BlockPlan,
+    in_lanes: Sequence[int],
+    out_lanes: int,
+    remapper: RemapperConfig,
+    chunk: int,
+) -> int:
+    """HBM bytes the block specs of one kernel call over `plan` move
+    (kernels/blocked.py): every stream block; an input-factor tile at each
+    tile-id change; the accumulator tile, read and written at each change;
+    and every tile afresh at the first step of each `chunk`-step call of a
+    chunked grid (`blocked.chunk_blocks`)."""
+    stream, factor, out = tile_bytes(
+        plan.nblocks, plan.tile_fills(chunk=chunk), blk=plan.blk,
+        tile_i=plan.tile_i, in_tiles=plan.in_tiles, in_lanes=in_lanes,
+        out_lanes=out_lanes, remapper=remapper,
+    )
+    return stream + factor + 2 * out
+
+
 def _kernel_times(
     cfg: MemoryControllerConfig,
     rank: int,
@@ -120,19 +171,14 @@ def _kernel_times(
     geometry so 'exact' estimates stay exact when a plan was built with
     different tiles than cfg describes."""
     rp = _rank_padded(rank)
-    c, r = cfg.cache, cfg.remapper
+    c = cfg.cache
     tile_i = c.tile_i if tile_i is None else tile_i
     in_tiles = c.input_tiles(n_in) if in_tiles is None else in_tiles
     blk = cfg.dma.blk if blk is None else blk
-    # stream: value + N local index vectors (output + N-1 inputs), element
-    # widths from the Remapper configuration (not hardcoded 4-byte literals)
-    stream_bytes = nblocks * blk * (r.value_bytes + (n_in + 1) * r.index_bytes)
-    factor_bytes = (
-        sum(fills[chr(ord("B") + n)] * t for n, t in enumerate(in_tiles))
-        * rp
-        * r.value_bytes
+    stream_bytes, factor_bytes, out_bytes = tile_bytes(
+        nblocks, fills, blk=blk, tile_i=tile_i, in_tiles=in_tiles,
+        in_lanes=(rp,) * n_in, out_lanes=rp, remapper=cfg.remapper,
     )
-    out_bytes = fills["A"] * tile_i * rp * r.value_bytes
     # one-hot segment matmul (TI x blk)@(blk x Rp) + hadamard/gather vector
     # work (one multiply+gather pair per input mode)
     flops = nblocks * (2 * tile_i * blk * rp + (2 + 2 * n_in) * blk * rp)
@@ -183,19 +229,15 @@ def _ttmc_kernel_times(
     the one-hot segment matmul."""
     n_in = len(in_ranks)
     pp = _rank_padded(math.prod(in_ranks))
-    c, r = cfg.cache, cfg.remapper
+    c = cfg.cache
     tile_i = c.tile_i if tile_i is None else tile_i
     in_tiles = c.input_tiles(n_in) if in_tiles is None else in_tiles
     blk = cfg.dma.blk if blk is None else blk
-    stream_bytes = nblocks * blk * (r.value_bytes + (n_in + 1) * r.index_bytes)
-    factor_bytes = (
-        sum(
-            fills[chr(ord("B") + n)] * t * _rank_padded(rk)
-            for n, (t, rk) in enumerate(zip(in_tiles, in_ranks))
-        )
-        * r.value_bytes
+    stream_bytes, factor_bytes, out_bytes = tile_bytes(
+        nblocks, fills, blk=blk, tile_i=tile_i, in_tiles=in_tiles,
+        in_lanes=tuple(_rank_padded(rk) for rk in in_ranks), out_lanes=pp,
+        remapper=cfg.remapper,
     )
-    out_bytes = fills["A"] * tile_i * pp * r.value_bytes
     # Kronecker chain: after input mode k the per-element row is prod(R_1..R_k)
     # wide; each widening step is one multiply per produced element (+ the
     # gather), then the one-hot segment matmul runs at the padded width.
@@ -358,19 +400,15 @@ def _tt_kernel_times(
     n_in = len(in_pairs)
     out_cols = out_pair[0] * out_pair[1]
     pp = _rank_padded(out_cols)
-    c, r = cfg.cache, cfg.remapper
+    c = cfg.cache
     tile_i = c.tile_i if tile_i is None else tile_i
     in_tiles = c.input_tiles(n_in) if in_tiles is None else in_tiles
     blk = cfg.dma.blk if blk is None else blk
-    stream_bytes = nblocks * blk * (r.value_bytes + (n_in + 1) * r.index_bytes)
-    factor_bytes = (
-        sum(
-            fills[chr(ord("B") + n)] * t * _rank_padded(a * b)
-            for n, (t, (a, b)) in enumerate(zip(in_tiles, in_pairs))
-        )
-        * r.value_bytes
+    stream_bytes, factor_bytes, out_bytes = tile_bytes(
+        nblocks, fills, blk=blk, tile_i=tile_i, in_tiles=in_tiles,
+        in_lanes=tuple(_rank_padded(a * b) for a, b in in_pairs), out_lanes=pp,
+        remapper=cfg.remapper,
     )
-    out_bytes = fills["A"] * tile_i * pp * r.value_bytes
     # Interface chains: folding core k into a chain vector is a (rl_k, rr_k)
     # matrix-vector product (2*rl*rr flops per element); the Kronecker of
     # the two finished interfaces plus the value scale adds 2*out_cols; the

@@ -206,11 +206,13 @@ class BlockPlan:
         return self.in_rows[1]
 
     # --- locality statistics (feed the PMS / Cache-Engine model) ---
-    def tile_fills(self) -> dict[str, int]:
+    def tile_fills(self, chunk: int | None = None) -> dict[str, int]:
         """Number of HBM->VMEM tile fetches Pallas will issue: a tile is
         re-fetched only when the block's tile id *changes* between consecutive
         grid steps (Pallas skips the copy when the index map is unchanged —
-        the run-length structure of the plan IS the cache).
+        the run-length structure of the plan IS the cache).  With `chunk`,
+        the grid runs as calls of at most `chunk` steps, and each call
+        fetches every tile afresh at its first step.
 
         Keys: "A" for the output accumulator tile, then one letter per input
         mode ("B", "C", "D", "E", ...)."""
@@ -218,7 +220,10 @@ class BlockPlan:
         def fills(ids: np.ndarray) -> int:
             if ids.size == 0:
                 return 0
-            return int(1 + np.count_nonzero(ids[1:] != ids[:-1]))
+            fresh = ids[1:] != ids[:-1]
+            if chunk is not None:
+                fresh |= np.arange(1, ids.size) % chunk == 0
+            return int(1 + np.count_nonzero(fresh))
 
         out = {"A": fills(self.block_it)}
         for n, ids in enumerate(self.block_in):
@@ -490,13 +495,12 @@ def _assemble_plan(
 
 def _record_plan_metrics(plan: BlockPlan, dt: float, builder: str) -> None:
     """Layout statistics every build records (docs/observability.md): build
-    wall time, padding/occupancy of the padded stream, block count, and the
+    wall time, padding of the padded stream, block count, and the
     blocks-per-output-tile imbalance (max over occupied tiles / mean — the
     skew the Cache Engine's A-tile residency sees)."""
     pad = plan.padding_fraction()
     _metrics.histogram("plan.build_seconds", builder=builder).observe(dt)
     _metrics.histogram("plan.padding_fraction").observe(pad)
-    _metrics.histogram("plan.occupancy").observe(1.0 - pad)
     _metrics.histogram("plan.nblocks").observe(plan.nblocks)
     if plan.block_it.size:
         per_tile = np.bincount(plan.block_it)

@@ -64,6 +64,7 @@ from .workspace import (
     _plan_device_arrays,
     planned_layout_bytes,
     sharded_layout_bytes,
+    sweep_scope,
 )
 
 __all__ = [
@@ -116,6 +117,10 @@ class PlannedMTTKRP:
 
     def __post_init__(self):
         self.layout = _plan_device_arrays(self.plan)
+
+    @property
+    def out_cols(self) -> int:
+        return self.rank
 
     def __call__(self, *in_factors: jax.Array) -> jax.Array:
         """Factors for the N-1 *input* modes (plan.in_modes order).
@@ -523,26 +528,29 @@ class PlannedCPALS(PlannedWorkspace):
             lam = None
             for m in range(nmodes):
                 p = ops[m].plan
-                in_facs = tuple(
-                    facs[im][: p.in_rows[n]] for n, im in enumerate(p.in_modes)
-                )
-                out = mttkrp_pallas_call(
-                    *layouts[m],
-                    in_facs,
-                    tile_i=p.tile_i,
-                    in_tiles=p.in_tiles,
-                    out_rows=p.out_rows,
-                )
-                mt = out[: shape[m], :rank]
+                with sweep_scope("cp", "kernel", m):
+                    in_facs = tuple(
+                        facs[im][: p.in_rows[n]] for n, im in enumerate(p.in_modes)
+                    )
+                    out = mttkrp_pallas_call(
+                        *layouts[m],
+                        in_facs,
+                        tile_i=p.tile_i,
+                        in_tiles=p.in_tiles,
+                        out_rows=p.out_rows,
+                    )
+                with sweep_scope("cp", "update", m):
+                    mt = out[: shape[m], :rank]
+                    true = [f[:s, :rank] for f, s in zip(facs, shape)]
+                    true, lam = _update_mode(mt, true, m, first)
+                    # Re-pad in place of the old padded factor (padding rows
+                    # and lanes stay exactly zero, so grams/fit in padded
+                    # space match the true-shape computation bit for bit).
+                    f = true[m]
+                    facs[m] = jnp.zeros((prows[m], rp), f.dtype).at[: shape[m], :rank].set(f)
+            with sweep_scope("cp", "fit"):
                 true = [f[:s, :rank] for f, s in zip(facs, shape)]
-                true, lam = _update_mode(mt, true, m, first)
-                # Re-pad in place of the old padded factor (padding rows and
-                # lanes stay exactly zero, so grams/fit in padded space match
-                # the true-shape computation bit for bit).
-                f = true[m]
-                facs[m] = jnp.zeros((prows[m], rp), f.dtype).at[: shape[m], :rank].set(f)
-            true = [f[:s, :rank] for f, s in zip(facs, shape)]
-            fit = fit_value(idx, val, true, lam, norm_x_sq)
+                fit = fit_value(idx, val, true, lam, norm_x_sq)
             return tuple(facs), lam, fit
 
         return jax.jit(sweep, static_argnames=("first",))
@@ -561,6 +569,9 @@ class PlannedCPALS(PlannedWorkspace):
 
     def _sweep_call(self, facs, *args, it: int):
         return self.sweep(facs, *args, first=(it == 0))
+
+    def _sweep_variants(self) -> tuple[dict, ...]:
+        return ({"first": True}, {"first": False})
 
     def mttkrp_fn(self, indices, values, factors, mode, out_rows):
         """The `cp_als(mttkrp_fn=...)` seam: the stream args are ignored —

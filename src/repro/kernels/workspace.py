@@ -25,6 +25,7 @@ format-specific sweep body IS the format.
 """
 from __future__ import annotations
 
+import re
 import time
 from typing import Any, Sequence
 
@@ -33,9 +34,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.loop import DecompositionDiverged, GuardState, finish_iter
+from ..core.pms import kernel_fetch_bytes
 from ..core.remap import BlockPlan
 from ..obs import metrics as _metrics
 from ..obs import trace as _trace
+from .blocked import chunk_blocks
 from .mttkrp_pallas import pad_factor, rank_padded
 
 __all__ = [
@@ -44,7 +47,35 @@ __all__ = [
     "planned_layout_bytes",
     "sharded_layout_bytes",
     "plan_stream",
+    "sweep_scope",
 ]
+
+# `<fmt>.m<n>.kernel`, `<fmt>.m<n>.update` or `<fmt>.fit`: the one pattern
+# of the names `sweep_scope` makes.
+_SCOPE_RE = re.compile(r"(?:cp|tucker|tt)\.(?:m\d+\.(?:kernel|update)|fit)")
+_INSTRUCTION_RE = re.compile(r"^\s*(?:ROOT )?%(\S+) = (.*)$", re.M)
+_OP_NAME_RE = re.compile(r'\bop_name="([^"]*)"')
+
+
+def sweep_scope(fmt: str, part: str, mode: int | None = None):
+    """The `jax.named_scope` of one part of a single-device sweep:
+    `<fmt>.m<mode>.kernel` around mode `mode`'s kernel call,
+    `<fmt>.m<mode>.update` around its update, `<fmt>.fit` around the fit.
+    A scope adds op_name metadata to the compiled program and changes no
+    operation."""
+    return jax.named_scope(f"{fmt}.{part}" if mode is None else f"{fmt}.m{mode}.{part}")
+
+
+def _scope_map(hlo_text: str) -> dict[str, str | None]:
+    """{instruction name: sweep scope} of every instruction of a compiled
+    module: the `sweep_scope` its op_name metadata passes through, None
+    where there is none."""
+    out = {}
+    for name, rest in _INSTRUCTION_RE.findall(hlo_text):
+        op_name = _OP_NAME_RE.search(rest)
+        parts = op_name[1].split("/") if op_name else ()
+        out[name] = next((p for p in parts if _SCOPE_RE.fullmatch(p)), None)
+    return out
 
 
 def plan_stream(plan: BlockPlan) -> tuple[np.ndarray, np.ndarray]:
@@ -179,6 +210,25 @@ class PlannedWorkspace:
 
     _sweep_fn = None  # instance attribute on first `sweep` call
     _fallback_fn = None  # instance attribute on first fallback degradation
+    fetch_bytes: dict[int, int] | None = None  # per mode, set once at build
+    predicted_sweep_s: float | None = None  # PMS sweep seconds, set once at build
+
+    def __post_init__(self):
+        """Counted once per workspace, from its built plans: the HBM bytes
+        each mode's kernel call moves (`kernel.fetch_bytes{mode=}`) and the
+        PMS-predicted sweep seconds that traced `sweep` spans carry."""
+        pads = self.rank_pads
+        self.fetch_bytes = {}
+        for m, op in self.ops.items():
+            p = op.plan
+            self.fetch_bytes[m] = kernel_fetch_bytes(
+                p, tuple(pads[im] for im in p.in_modes), rank_padded(op.out_cols),
+                op.cfg.remapper, chunk_blocks(1 + p.n_in),
+            )
+            _metrics.gauge("kernel.fetch_bytes", mode=m).set(self.fetch_bytes[m])
+        self.predicted_sweep_s = float(
+            sum(e.t_total for e in self.pms_estimates().values())
+        )
 
     @property
     def nmodes(self) -> int:
@@ -207,6 +257,12 @@ class PlannedWorkspace:
 
     def _build_sweep(self):
         raise NotImplementedError
+
+    def _sweep_variants(self) -> tuple[dict, ...]:
+        """Keyword arguments of each distinct program `drive` runs; formats
+        whose sweep retraces on a static argument (CP's `first`) override
+        this."""
+        return ({},)
 
     def pad_factors(self, factors: Sequence[jax.Array]) -> tuple[jax.Array, ...]:
         """One pad per mode for the whole decomposition (not N x iters)."""
@@ -242,6 +298,26 @@ class PlannedWorkspace:
         `.compile()` gives the program `sweep` runs: its compile time and
         its HLO text, where a compiled Pallas kernel is a `tpu_custom_call`."""
         return self._jitted_sweep().lower(*self._sweep_operands(facs, args), **kwargs)
+
+    def sweep_scopes(self, *args) -> list[dict]:
+        """For each compiled program `drive` runs (CP: the first and the
+        steady sweep), `{"module": HLO module name, "scopes": {instruction
+        name: sweep scope or None}}` over every instruction of its compiled
+        text.  A profiler's device op events carry their instruction name
+        but no metadata, and CP's two programs share one module name: an
+        execution's instruction names tell which program ran, and this map
+        names each op's scope.  `args` are the sweep's arguments after the
+        factors (arrays or `jax.ShapeDtypeStruct`s)."""
+        facs = tuple(
+            jax.ShapeDtypeStruct((rows, lanes), jnp.float32)
+            for rows, lanes in zip(self.padded_rows, self.rank_pads)
+        )
+        out = []
+        for kwargs in self._sweep_variants():
+            text = self.lower_sweep(facs, *args, **kwargs).compile().as_text()
+            out.append({"module": text.split(None, 2)[1].rstrip(","),
+                        "scopes": _scope_map(text)})
+        return out
 
     def _jitted_sweep(self):
         if self._sweep_fn is None:
@@ -303,7 +379,8 @@ class PlannedWorkspace:
         """
         gs = GuardState(guards) if guards is not None else None
         fits: list[float] = []
-        facs = self.pad_factors(factors)
+        with _trace.span("drive.pad", label=label):
+            facs = self.pad_factors(factors)
         aux = None
         sweep_call = self._sweep_call
         fb_active = False
@@ -351,14 +428,11 @@ class PlannedWorkspace:
         # Per-iteration observability (docs/observability.md): metric
         # handles are resolved once so the hot loop pays no registry lookup;
         # the per-sweep span carries the PMS-predicted sweep time when the
-        # format exposes it, which is what `obs.calibrate.join_trace` joins
+        # format has one, which is what `obs.calibrate.join_trace` joins
         # achieved_pct from.
         m_iter = _metrics.histogram("drive.iter_seconds", label=label)
-        m_delta = _metrics.histogram("drive.fit_delta", label=label)
         m_count = _metrics.counter("drive.iterations", label=label)
-        predicted_s = (
-            self._predicted_sweep_s() if _trace.active() is not None else None
-        )
+        predicted_s = self.predicted_sweep_s
 
         it = start
         prev_facs = None  # one-step history: the fallback rebase target
@@ -371,8 +445,6 @@ class PlannedWorkspace:
                     fit = float(fit)
                 m_iter.observe(time.perf_counter() - t_sweep)
                 m_count.inc()
-                if fits:
-                    m_delta.observe(fit - fits[-1])
                 reason = None
                 if gs is not None:
                     reason = gs.observe_fit(fit)
@@ -447,17 +519,8 @@ class PlannedWorkspace:
                 if stop:
                     break
                 it += 1
-        return self.unpad_factors(facs), aux, fits
-
-    def _predicted_sweep_s(self) -> float | None:
-        """PMS-predicted seconds for one full sweep when the format exposes
-        `pms_estimates` (PlannedCPALS / PlannedTucker / PlannedTT); None
-        otherwise.  Attached to traced sweep spans so a trace JSONL alone
-        carries everything `obs.calibrate.join_trace` needs."""
-        hook = getattr(self, "pms_estimates", None)
-        if hook is None:
-            return None
-        return float(sum(e.t_total for e in hook().values()))
+        with _trace.span("drive.unpad", label=label):
+            return self.unpad_factors(facs), aux, fits
 
 
 class ShardedWorkspace(PlannedWorkspace):
@@ -467,6 +530,9 @@ class ShardedWorkspace(PlannedWorkspace):
     with the sweep running as one jitted shard_map.  Subclasses additionally
     carry `stacks` / `dist` / `cfgs`; `_stream_args()` supplies the
     shard-stacked fit stream for formats whose fit walks the non-zeros."""
+
+    def __post_init__(self):
+        """The sharded sweeps keep no fetch count and no PMS prediction."""
 
     @property
     def nshards(self) -> int:

@@ -9,9 +9,9 @@ Three stdlib-only pieces (docs/observability.md):
     ``REPRO_TRACE=1`` (or a path), `trace.enable()`, or per call via
     ``decompose(..., trace=...)``.  Disabled calls are no-ops.
   * `obs.metrics` — always-on counters/gauges/histograms recorded by the
-    hot paths: drive-loop iteration times and fit deltas, plan-build and
-    padding/occupancy stats, plan-cache hit/miss/eviction latencies,
-    guard/restart/fallback/admission events, shard imbalance.
+    hot paths: drive-loop iteration times, plan-build and padding stats,
+    the kernels' HBM fetch bytes per mode, plan-cache hit/miss/eviction
+    latencies, guard/restart/fallback/admission events, shard imbalance.
   * `obs.calibrate` — joins the PMS `predict_*` estimates against measured
     sweep times (`achieved_pct`); feeds the `pms_accuracy` section of
     BENCH_kernel.json and `scripts/trace_report.py --pms`.

@@ -3,9 +3,9 @@
 Zero-dependency (stdlib only; jax is bridged lazily and optionally): the hot
 paths call `span("sweep", ...)` / `event("guard", ...)` unconditionally, and
 when no tracer is installed those calls compile down to one module-global
-read and the return of a shared no-op context manager — the traced-off
-overhead bound (<= 2% on a small-preset drive(), tests/test_obs.py) holds
-because a disabled call allocates nothing.
+read and the return of a shared no-op context manager: a disabled call
+allocates no span, opens no profiler annotation and records nothing
+(tests/test_obs.py counts it).
 
 Enable switches (process-global):
 

@@ -71,7 +71,8 @@ from ..kernels.ops import (
     planned_layout_bytes,
 )
 from ..kernels.ref import ttcore_ref
-from ..kernels.workspace import PlannedWorkspace
+from ..kernels.workspace import PlannedWorkspace, sweep_scope
+from ..obs import trace as _trace
 
 __all__ = [
     "TTState",
@@ -343,35 +344,40 @@ class PlannedTT(PlannedWorkspace):
 
         def sweep(layouts, facs, idx, val, norm_x_sq):
             facs = list(facs)
-            cores = [
-                matrix_to_core(facs[m][: shape[m], : lr[m]], *pairs[m])
-                for m in range(nmodes)
-            ]
-            # Right Grams once from the incoming cores; the left Gram runs
-            # ahead with each freshly solved core.
-            qs = _q_suffix(cores)
-            p = jnp.ones((1, 1), jnp.float32)
+            # Right Grams once from the incoming cores (the first mode's
+            # update needs them all); the left Gram runs ahead with each
+            # freshly solved core.
+            with sweep_scope("tt", "update", 0):
+                cores = [
+                    matrix_to_core(facs[m][: shape[m], : lr[m]], *pairs[m])
+                    for m in range(nmodes)
+                ]
+                qs = _q_suffix(cores)
+                p = jnp.ones((1, 1), jnp.float32)
             for m in range(nmodes):
                 op, pln = ops[m], ops[m].plan
-                in_mats = tuple(
-                    facs[im][: pln.in_rows[n]] for n, im in enumerate(pln.in_modes)
-                )
-                out = op.call_padded(in_mats, layouts[m])
-                b = out[: shape[m], : lr[m]]
-                w = _solve_core(jnp.kron(p, qs[m]), b)
-                cores[m] = matrix_to_core(w, *pairs[m])
-                # Re-pad in place of the old padded matrix (padding rows and
-                # lanes stay exactly zero, so the next mode's kernel gathers
-                # zeros for padding elements).
-                facs[m] = (
-                    jnp.zeros((prows[m], rps[m]), w.dtype)
-                    .at[: shape[m], : lr[m]]
-                    .set(w)
-                )
-                p = _p_next(p, cores[m])
-            inner = tt_inner(idx, val, cores)
-            resid_sq = jnp.maximum(norm_x_sq + p[0, 0] - 2.0 * inner, 0.0)
-            fit = 1.0 - jnp.sqrt(resid_sq) / jnp.sqrt(norm_x_sq)
+                with sweep_scope("tt", "kernel", m):
+                    in_mats = tuple(
+                        facs[im][: pln.in_rows[n]] for n, im in enumerate(pln.in_modes)
+                    )
+                    out = op.call_padded(in_mats, layouts[m])
+                with sweep_scope("tt", "update", m):
+                    b = out[: shape[m], : lr[m]]
+                    w = _solve_core(jnp.kron(p, qs[m]), b)
+                    cores[m] = matrix_to_core(w, *pairs[m])
+                    # Re-pad in place of the old padded matrix (padding rows
+                    # and lanes stay exactly zero, so the next mode's kernel
+                    # gathers zeros for padding elements).
+                    facs[m] = (
+                        jnp.zeros((prows[m], rps[m]), w.dtype)
+                        .at[: shape[m], : lr[m]]
+                        .set(w)
+                    )
+                    p = _p_next(p, cores[m])
+            with sweep_scope("tt", "fit"):
+                inner = tt_inner(idx, val, cores)
+                resid_sq = jnp.maximum(norm_x_sq + p[0, 0] - 2.0 * inner, 0.0)
+                fit = 1.0 - jnp.sqrt(resid_sq) / jnp.sqrt(norm_x_sq)
             return tuple(facs), None, fit
 
         return jax.jit(sweep)
@@ -525,15 +531,17 @@ def tt_als(
     pairs = _tt_bond_pairs(tr, nmodes)
     if init == "auto":
         init = "svd" if math.prod(st.shape) <= _TT_SVD_DENSE_LIMIT else "random"
-    if init == "svd":
-        cores = tt_svd(st, tr)
-    elif init == "random":
-        cores = init_tt_cores(jax.random.PRNGKey(seed), st.shape, tr)
-    else:
+    if init not in ("svd", "random"):
         raise ValueError(
             f"unknown init {init!r}: expected 'auto', 'svd' or 'random'"
         )
-    norm_x_sq = jnp.asarray(float(np.sum(st.values.astype(np.float64) ** 2)), jnp.float32)
+    with _trace.span("job.init"):
+        if init == "svd":
+            cores = tt_svd(st, tr)
+        else:
+            cores = init_tt_cores(jax.random.PRNGKey(seed), st.shape, tr)
+        norm_x_sq = jnp.asarray(
+            float(np.sum(st.values.astype(np.float64) ** 2)), jnp.float32)
     fits: list[float] = []
 
     check_planned_method(method, planned, devices, dist)
@@ -576,7 +584,8 @@ def tt_als(
             # Fast path: interface matrices padded once, updated in padded
             # space by one jitted sweep per iteration; folded back to cores
             # only for the TTState.
-            idx, val = jnp.asarray(st.indices), jnp.asarray(st.values)
+            with _trace.span("job.upload"):
+                idx, val = jnp.asarray(st.indices), jnp.asarray(st.values)
             mats = [core_to_matrix(c) for c in cores]
             mats, _, fits = planned.drive(
                 mats, (idx, val, norm_x_sq), iters=iters, tol=tol,

@@ -55,7 +55,8 @@ from ..core.memctrl import MemoryControllerConfig, TPUSpec
 from ..kernels.ops import PlannedTTMC, make_planned_ttmc, planned_layout_bytes
 from ..kernels.mttkrp_pallas import rank_padded
 from ..kernels.ref import ttmc_ref
-from ..kernels.workspace import PlannedWorkspace, plan_stream
+from ..kernels.workspace import PlannedWorkspace, plan_stream, sweep_scope
+from ..obs import trace as _trace
 
 __all__ = [
     "TuckerState",
@@ -202,27 +203,31 @@ class PlannedTucker(PlannedWorkspace):
 
         def sweep(layouts, facs, norm_x_sq):
             facs = list(facs)
-            y = None
+            last = nmodes - 1
             for m in range(nmodes):
                 op, p = ops[m], ops[m].plan
-                in_facs = tuple(
-                    facs[im][: p.in_rows[n]] for n, im in enumerate(p.in_modes)
-                )
-                out = op.call_padded(in_facs, layouts[m])
-                y = out[: shape[m], : op.out_cols]
-                u = _factor_from_unfolding(y, core_ranks[m])
-                # Re-pad in place of the old padded factor (padding rows and
-                # lanes stay exactly zero, so the next mode's kernel gathers
-                # zeros for padding elements).
-                facs[m] = (
-                    jnp.zeros((prows[m], rps[m]), u.dtype)
-                    .at[: shape[m], : core_ranks[m]]
-                    .set(u)
-                )
-            last = nmodes - 1
-            u_last = facs[last][: shape[last], : core_ranks[last]]
-            core = _core_from_unfolding(y, u_last, last, core_ranks)
-            return tuple(facs), core, core_fit_value(core, norm_x_sq)
+                with sweep_scope("tucker", "kernel", m):
+                    in_facs = tuple(
+                        facs[im][: p.in_rows[n]] for n, im in enumerate(p.in_modes)
+                    )
+                    out = op.call_padded(in_facs, layouts[m])
+                with sweep_scope("tucker", "update", m):
+                    y = out[: shape[m], : op.out_cols]
+                    u = _factor_from_unfolding(y, core_ranks[m])
+                    # Re-pad in place of the old padded factor (padding rows
+                    # and lanes stay exactly zero, so the next mode's kernel
+                    # gathers zeros for padding elements).
+                    facs[m] = (
+                        jnp.zeros((prows[m], rps[m]), u.dtype)
+                        .at[: shape[m], : core_ranks[m]]
+                        .set(u)
+                    )
+                    if m == last:
+                        u_last = facs[last][: shape[last], : core_ranks[last]]
+                        core = _core_from_unfolding(y, u_last, last, core_ranks)
+            with sweep_scope("tucker", "fit"):
+                fit = core_fit_value(core, norm_x_sq)
+            return tuple(facs), core, fit
 
         return jax.jit(sweep)
 
@@ -354,9 +359,10 @@ def tucker_hooi(
     """
     cr = _validated_core_ranks(st, core_ranks)
     nmodes = st.nmodes
-    key = jax.random.PRNGKey(seed)
-    factors = init_tucker_factors(key, st.shape, cr)
-    norm_x_sq = jnp.asarray(float(np.sum(st.values.astype(np.float64) ** 2)), jnp.float32)
+    with _trace.span("job.init"):
+        factors = init_tucker_factors(jax.random.PRNGKey(seed), st.shape, cr)
+        norm_x_sq = jnp.asarray(
+            float(np.sum(st.values.astype(np.float64) ** 2)), jnp.float32)
     fits: list[float] = []
 
     check_planned_method(method, planned, devices, dist)

@@ -180,3 +180,85 @@ def test_mttkrp_is_memory_bound_at_paper_scale(small_tensor):
     t_mem = t.bytes() / spec.hbm_bw
     t_cmp = 2 * t.compute_ops / spec.peak_flops
     assert t_mem > 10 * t_cmp
+
+
+def _hand_plan(block_it, block_in, *, blk=4, tile_i=8, in_tiles=(16, 32)):
+    from repro.core.remap import BlockPlan
+
+    nb = len(block_it)
+    zeros = np.zeros(nb * blk, np.int32)
+    return BlockPlan(
+        vals=np.zeros(nb * blk, np.float32), iloc=zeros,
+        in_locs=(zeros,) * len(in_tiles),
+        block_it=np.asarray(block_it, np.int32),
+        block_in=tuple(np.asarray(t, np.int32) for t in block_in),
+        tile_i=tile_i, in_tiles=in_tiles, blk=blk, out_rows=2 * tile_i,
+        in_rows=tuple(4 * t for t in in_tiles), mode=0, in_modes=(1, 2), nnz=nb,
+    )
+
+
+def test_kernel_fetch_bytes_hand_count():
+    """Six grid steps in calls of four: every tile is fetched at the first
+    step of each call and at each change of its id; the accumulator tile is
+    read and written at each of its fills."""
+    from repro.core.memctrl import RemapperConfig
+    from repro.core.pms import kernel_fetch_bytes
+
+    plan = _hand_plan([0, 0, 0, 1, 1, 1], ([0, 1, 1, 2, 2, 2], [5, 5, 5, 5, 6, 6]))
+    # whole grid: A 0|1 -> 2, B 0|1|2 -> 3, C 5|6 -> 2; calls of four add a
+    # fresh fetch of every tile at step 4: A 3, B 4, C 2 (C changes there).
+    assert plan.tile_fills() == {"A": 2, "B": 3, "C": 2}
+    assert plan.tile_fills(chunk=4) == {"A": 3, "B": 4, "C": 2}
+    stream = 6 * 4 * (4 + 3 * 4)
+    factor = (4 * 16 * 128 + 2 * 32 * 256) * 4
+    acc = 3 * 8 * 128 * 4
+    got = kernel_fetch_bytes(plan, (128, 256), 128, RemapperConfig(), chunk=4)
+    assert got == stream + factor + 2 * acc == 123_264
+
+
+def test_kernel_fetch_bytes_matches_pms_byte_terms(small_tensor):
+    """Over one call (no chunk boundary) the fetch count is the PMS's own
+    byte terms, with the accumulator read as well as written — MTTKRP at one
+    lane width, TTMc at each input rank's and the Kronecker width."""
+    from repro.core.pms import kernel_fetch_bytes
+
+    spec = TPUSpec()
+    cfg = MemoryControllerConfig()
+    plan = plan_blocks(small_tensor, 0)
+    one_call = plan.nblocks
+    est = predict_from_plan(plan, 16, cfg, spec)
+    got = kernel_fetch_bytes(plan, (128, 128), 128, cfg.remapper, chunk=one_call)
+    assert got == pytest.approx((est.t_stream + est.t_factor + 2 * est.t_out) * spec.hbm_bw,
+                                rel=1e-12)
+    est = predict_ttmc(plan, (8, 130, 20), cfg, spec)  # in_modes (1, 2): ranks 130, 20
+    got = kernel_fetch_bytes(plan, (256, 128), 2688, cfg.remapper, chunk=one_call)
+    assert got == pytest.approx((est.t_stream + est.t_factor + 2 * est.t_out) * spec.hbm_bw,
+                                rel=1e-12)
+    # calls of fewer steps only add fresh fetches
+    assert kernel_fetch_bytes(plan, (128, 128), 128, cfg.remapper, chunk=7) > \
+        kernel_fetch_bytes(plan, (128, 128), 128, cfg.remapper, chunk=one_call)
+
+
+def test_workspace_records_fetch_bytes_once(small_tensor):
+    """Each single-device workspace counts its kernels' fetches at build,
+    with the chunking and lane widths its kernels run, and records them as
+    `kernel.fetch_bytes{mode=}`."""
+    from repro.core.pms import kernel_fetch_bytes
+    from repro.kernels.blocked import chunk_blocks
+    from repro.kernels.ops import make_planned_cp_als
+    from repro.obs import metrics
+    from repro.tucker.hooi import make_planned_tucker
+
+    metrics.reset()
+    ws = make_planned_cp_als(small_tensor, 16)
+    for m, op in ws.ops.items():
+        assert ws.fetch_bytes[m] == kernel_fetch_bytes(
+            op.plan, (128, 128), 128, op.cfg.remapper, chunk_blocks(3))
+    gauges = metrics.snapshot()["gauges"]
+    assert {k: v for k, v in gauges.items() if k.startswith("kernel.fetch_bytes")} == {
+        f"kernel.fetch_bytes{{mode={m}}}": float(b) for m, b in ws.fetch_bytes.items()}
+    tk = make_planned_tucker(small_tensor, (8, 130, 20))
+    p = tk.ops[0].plan  # input ranks 130 and 20 (lanes 256, 128); 2600 columns -> 2688
+    assert tk.fetch_bytes[0] == kernel_fetch_bytes(p, (256, 128), 2688, tk.ops[0].cfg.remapper,
+                                                   chunk_blocks(3))
+    metrics.reset()
