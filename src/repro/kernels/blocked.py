@@ -12,16 +12,25 @@ product (a `contract(rows) -> (blk, out_cols)` function):
   * Cache Engine — one `(tile_n, width_n)` factor tile per input mode,
                    selected by scalar-prefetched tile ids; Pallas skips the
                    copy when the id repeats.  Rows are gathered from the
-                   VMEM tile by a one-hot `(tile_n, blk)^T @ (tile_n, width)`
+                   VMEM tile by a one-hot `(blk, tile_n) @ (tile_n, width)`
                    matmul on the MXU.
   * Approach 1   — blocks are sorted by output tile, so an accumulator tile
                    is resident across its run and written back once.
-  * MXU          — the segment sum is a value-weighted one-hot
-                   `(tile_i, blk) @ (blk, out_cols)` matmul.
+  * MXU          — the segment sum is a one-hot `(tile_i, blk) @ (blk,
+                   out_cols)` matmul of the value-weighted contributions.
 
-Every dot runs at `Precision.HIGHEST`: the one-hot operands are exact in
-bf16, but the factor values are f32 and a default-precision MXU pass would
-round them to bf16.
+Exact bf16 passes.  In every matmul here one operand is exactly 0 or 1 (a
+one-hot or a `spread` matrix), so it is exact in bf16.  The other, real
+operand is f32, and any finite f32 is the exact sum of three bf16 pieces
+(`pieces`: 8 + 8 + 8 significand bits), so `dot01` runs one single-pass
+bf16 MXU matmul per piece with float32 accumulation.  Every product is then
+exact and the sums accumulate in float32: the float32 result in three
+passes, where the MXU's own float32 emulation takes six and still drops the
+low-by-low terms.  A real operand already in bf16 is one piece, one pass.
+The segment sum keeps its one-hot 0/1 by multiplying the values into the
+contribution first (an f32 multiply on the VPU).  The two lane vectors a
+step needs down its rows, the local indices of a gather and the values,
+get there by a transpose (`_rows`), which moves data without arithmetic.
 
 SMEM chunking.  The tile-id streams are scalar-prefetched, so each call
 holds `(1 + n_in)` int32 per grid step in SMEM.  A mode with more blocks
@@ -43,9 +52,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..platform import device_spec, interpret_mode
 
-__all__ = ["HIGHEST", "blocked_call", "chunk_blocks", "dot", "spread"]
+__all__ = ["blocked_call", "chunk_blocks", "dot01", "pieces", "spread"]
 
-HIGHEST = jax.lax.Precision.HIGHEST
 
 # Fraction of SMEM the tile-id streams of one call may take; the rest is
 # left to the pipeline's own scalars.
@@ -66,13 +74,48 @@ def _vmem_limit_bytes() -> int:
     return int(spec.vmem_bytes * spec.vmem_usable_frac)
 
 
-def dot(a: jax.Array, b: jax.Array) -> jax.Array:
-    return jax.lax.dot(a, b, precision=HIGHEST, preferred_element_type=jnp.float32)
+def _top8(x: jax.Array) -> jax.Array:
+    """f32 `x` cut to its sign, exponent and top 8 significand bits (the
+    low 16 bits of its pattern zeroed): a bf16 value, held as f32."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32) & jnp.int32(-65536)  # 0xFFFF0000
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def pieces(x: jax.Array) -> tuple[jax.Array, ...]:
+    """bf16 arrays whose float32 sum, in order, is `x` bit for bit, for any
+    finite `x`: `x` itself when it is bf16, else hi, mid and lo.  hi is x's
+    top 8 significand bits, mid the top 8 of the remainder x - hi (at most
+    16 bits), lo the rest (at most 8); every piece converts to bf16 and
+    every subtraction is exact."""
+    if x.dtype == jnp.bfloat16:
+        return (x,)
+    hi = _top8(x)
+    r = x - hi
+    mid = _top8(r)
+    return tuple(p.astype(jnp.bfloat16) for p in (hi, mid, r - mid))
+
+
+def dot01(a: jax.Array, b: jax.Array) -> jax.Array:
+    """`a @ b` in float32, exactly, where one operand is 0/1 in bf16 and the
+    other is real: one single-pass bf16 MXU matmul per bf16 piece of the
+    real operand, summed in float32.  Both in bf16 is one pass."""
+    real_left = b.dtype == jnp.bfloat16
+    real, e01 = (a, b) if real_left else (b, a)
+    if e01.dtype != jnp.bfloat16:
+        raise TypeError(f"dot01 needs a bf16 0/1 operand, got {a.dtype} and {b.dtype}")
+    out = None
+    for piece in pieces(real):
+        term = jnp.dot(*((piece, e01) if real_left else (e01, piece)),
+                       # one pass, also under a caller's default_matmul_precision
+                       precision=jax.lax.Precision.DEFAULT,
+                       preferred_element_type=jnp.float32)
+        out = term if out is None else out + term
+    return out
 
 
 def spread(rows: int, cols: int, *, width: int, stride: int, count: int,
            transpose: bool = False) -> jax.Array:
-    """0/1 matrix E (rows, cols) with E[j, c] = 1 iff c < width and
+    """0/1 bf16 matrix E (rows, cols) with E[j, c] = 1 iff c < width and
     (c // stride) % count == j — or with the roles of the axes swapped when
     `transpose`.  `x @ E` spreads the columns of x into a Kronecker column
     layout without a lane-splitting reshape: stride = the product of the
@@ -81,18 +124,21 @@ def spread(rows: int, cols: int, *, width: int, stride: int, count: int,
     j = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), jdim)
     c = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), cdim)
     hit = (c < width) & (jax.lax.rem(jax.lax.div(c, stride), count) == j)
-    return hit.astype(jnp.float32)
+    return hit.astype(jnp.bfloat16)
+
+
+def _rows(v: jax.Array, width: int) -> jax.Array:
+    """A lane vector v (1, blk) as the (blk, width) array whose every column
+    is v: a transpose, exact."""
+    return jnp.transpose(jnp.broadcast_to(v, (width, v.shape[1])))
 
 
 def _gather(loc: jax.Array, tile: jax.Array) -> jax.Array:
     """Rows `loc` (1, blk) of a VMEM tile (tile_n, w) as (blk, w): a one-hot
-    matmul, transposed on the MXU so that `loc` stays a lane vector."""
-    onehot_t = jax.lax.broadcasted_iota(jnp.int32, (tile.shape[0], loc.shape[1]), 0) == loc
-    return jax.lax.dot_general(
-        onehot_t.astype(jnp.float32), tile.astype(jnp.float32),
-        (((0,), (0,)), ((), ())),
-        precision=HIGHEST, preferred_element_type=jnp.float32,
-    )
+    (blk, tile_n) matmul, exact."""
+    tile_n = tile.shape[0]
+    onehot = jax.lax.broadcasted_iota(jnp.int32, (loc.shape[1], tile_n), 1) == _rows(loc, tile_n)
+    return dot01(onehot.astype(jnp.bfloat16), tile)
 
 
 def _kernel(contract, tile_i: int, n_in: int, off_ref, it_ref, *refs):
@@ -120,10 +166,10 @@ def _kernel(contract, tile_i: int, n_in: int, off_ref, it_ref, *refs):
 
     rows = [_gather(l[...], f[...]) for l, f in zip(loc_refs, fac_refs)]
     contrib = contract(rows)  # (blk, out_cols)
-    blk = contrib.shape[0]
+    blk, out_cols = contrib.shape
+    weighted = contrib * _rows(vals_ref[...], out_cols)
     seg = jax.lax.broadcasted_iota(jnp.int32, (tile_i, blk), 0) == iloc_ref[...]
-    weighted = jnp.where(seg, vals_ref[...].astype(jnp.float32), 0.0)
-    out_ref[...] += dot(weighted, contrib)
+    out_ref[...] += dot01(seg.astype(jnp.bfloat16), weighted)
 
 
 def _chunk_call(contract, offset, ids, streams, factors, acc, *, tile_i,

@@ -19,10 +19,11 @@ W_k = transpose(G_k, (1,0,2)).reshape(I_k, rl_k*rr_k) (row-major — rl slow,
 rr fast), lane-padded to rank_padded(rl_k*rr_k).  Gathered rows fold into
 the left chain (inputs left of the output mode, ascending) or the right
 chain (inputs right of it, descending).  Both chains are (blk, cw) vectors,
-cw the lane padding of the widest bond, and every step is a 0/1 matmul
-spread, an elementwise product and a 0/1 matmul reduction — no reshape
-splits a lane dimension.  `plan.in_modes` is ascending, so n_left — the
-number of left-chain inputs — equals the output mode.
+cw the lane padding of the widest bond, and every step is an exact 0/1
+matmul spread (`dot01`), an elementwise product and an exact 0/1 matmul
+reduction — no reshape splits a lane dimension.  `plan.in_modes` is
+ascending, so n_left — the number of left-chain inputs — equals the output
+mode.
 """
 from __future__ import annotations
 
@@ -32,7 +33,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
-from .blocked import blocked_call, dot, spread
+from .blocked import blocked_call, dot01, spread
 from .mttkrp_pallas import rank_padded
 
 __all__ = ["ttcore_pallas_call", "tt_out_pair", "tt_out_cols"]
@@ -70,22 +71,22 @@ def _chain_contract(
     blk = rows[0].shape[0]
     cw = rank_padded(max(max(p) for p in in_rank_pairs))
     lanes = jax.lax.broadcasted_iota(jnp.int32, (blk, cw), 1)
-    unit = (lanes == 0).astype(jnp.float32)  # the width-1 chain start
+    unit = (lanes == 0).astype(jnp.bfloat16)  # the width-1 chain start, 0/1
     left = right = unit
     for n in range(n_left):
         rl, rr = in_rank_pairs[n]
         w = rows[n].shape[1]
-        prod = dot(left, spread(cw, w, width=rl * rr, stride=rr, count=rl)) * rows[n]
-        left = dot(prod, spread(w, cw, width=rl * rr, stride=1, count=rr, transpose=True))
+        prod = dot01(left, spread(cw, w, width=rl * rr, stride=rr, count=rl)) * rows[n]
+        left = dot01(prod, spread(w, cw, width=rl * rr, stride=1, count=rr, transpose=True))
     for n in range(len(in_rank_pairs) - 1, n_left - 1, -1):
         rl, rr = in_rank_pairs[n]
         w = rows[n].shape[1]
-        prod = rows[n] * dot(right, spread(cw, w, width=rl * rr, stride=1, count=rr))
-        right = dot(prod, spread(w, cw, width=rl * rr, stride=rr, count=rl, transpose=True))
+        prod = rows[n] * dot01(right, spread(cw, w, width=rl * rr, stride=1, count=rr))
+        right = dot01(prod, spread(w, cw, width=rl * rr, stride=rr, count=rl, transpose=True))
     rl, rr = tt_out_pair(in_rank_pairs, n_left)
     return (
-        dot(left, spread(cw, pp, width=rl * rr, stride=rr, count=rl))
-        * dot(right, spread(cw, pp, width=rl * rr, stride=1, count=rr))
+        dot01(left, spread(cw, pp, width=rl * rr, stride=rr, count=rl))
+        * dot01(right, spread(cw, pp, width=rl * rr, stride=1, count=rr))
     )
 
 

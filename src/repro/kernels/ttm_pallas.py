@@ -15,9 +15,9 @@ access pattern is IDENTICAL, so the kernel runs on the same scaffold
 Each input factor keeps its OWN rank R_m (lane-padded to rank_padded(R_m));
 the output carries P = prod(R_m) columns (lane-padded to cols_padded(P)).
 The Kronecker product is built without reshapes: each input's gathered rows
-are spread into the output column layout by a 0/1 matmul (column c takes
-row entry (c // stride_m) % R_m, stride_m the product of the later ranks),
-and the spread rows are multiplied elementwise.
+are spread into the output column layout by an exact 0/1 matmul (`dot01`;
+column c takes row entry (c // stride_m) % R_m, stride_m the product of the
+later ranks), and the spread rows are multiplied elementwise.
 """
 from __future__ import annotations
 
@@ -27,7 +27,7 @@ from typing import Sequence
 
 import jax
 
-from .blocked import blocked_call, dot, spread
+from .blocked import blocked_call, dot01, spread
 from .mttkrp_pallas import rank_padded
 
 __all__ = ["ttmc_pallas_call", "cols_padded", "kron_cols"]
@@ -52,7 +52,7 @@ def _kron_contract(in_ranks: tuple[int, ...], pp: int, rows: list) -> jax.Array:
     for n, (r, x) in enumerate(zip(in_ranks, rows)):
         stride = kron_cols(in_ranks[n + 1 :])
         e = spread(x.shape[1], pp, width=p, stride=stride, count=r)
-        term = dot(x, e)
+        term = dot01(x, e)
         contrib = term if contrib is None else contrib * term
     return contrib
 

@@ -215,7 +215,8 @@ class PlannedWorkspace:
 
     def __post_init__(self):
         """Counted once per workspace, from its built plans: the HBM bytes
-        each mode's kernel call moves (`kernel.fetch_bytes{mode=}`) and the
+        each mode's kernel call moves (`kernel.fetch_bytes{mode=}`), its
+        grid steps (`kernel.grid_steps{mode=}`, the plan's blocks) and the
         PMS-predicted sweep seconds that traced `sweep` spans carry."""
         pads = self.rank_pads
         self.fetch_bytes = {}
@@ -226,6 +227,7 @@ class PlannedWorkspace:
                 op.cfg.remapper, chunk_blocks(1 + p.n_in),
             )
             _metrics.gauge("kernel.fetch_bytes", mode=m).set(self.fetch_bytes[m])
+            _metrics.gauge("kernel.grid_steps", mode=m).set(p.nblocks)
         self.predicted_sweep_s = float(
             sum(e.t_total for e in self.pms_estimates().values())
         )
@@ -532,7 +534,8 @@ class ShardedWorkspace(PlannedWorkspace):
     shard-stacked fit stream for formats whose fit walks the non-zeros."""
 
     def __post_init__(self):
-        """The sharded sweeps keep no fetch count and no PMS prediction."""
+        """The sharded sweeps keep no fetch or step count and no PMS
+        prediction."""
 
     @property
     def nshards(self) -> int:

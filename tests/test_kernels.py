@@ -9,14 +9,17 @@ from hypothesis import given, settings, strategies as st
 from repro.core.coo import SparseTensor, frostt_like, random_factors, synthetic_tensor
 from repro.core.memctrl import CacheEngineConfig, DMAEngineConfig, MemoryControllerConfig
 from repro.core.remap import plan_blocks
+from repro.kernels import blocked
 from repro.kernels.mttkrp_pallas import mttkrp_pallas_call, pad_factor, rank_padded
 from repro.kernels.ops import (
     make_planned_mttkrp,
+    make_planned_ttcore,
+    make_planned_ttmc,
     mttkrp_auto,
     plan_cache_clear,
     plan_cache_stats,
 )
-from repro.kernels.ref import mttkrp_plan_ref, mttkrp_ref
+from repro.kernels.ref import mttkrp_plan_ref, mttkrp_ref, ttcore_plan_ref, ttmc_plan_ref
 
 
 def _totals(stats: dict) -> tuple[int, int]:
@@ -96,6 +99,111 @@ def test_kernel_vs_plan_ref(tiny_tensor):
         interpret=True,
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-4, atol=1e-4)
+
+
+def _wide_f32(n: int, seed: int) -> np.ndarray:
+    """Random f32 of both signs over exponents 2^-100 .. 2^127, with +-0,
+    +-1, the ends of that range and the largest finite f32."""
+    rng = np.random.default_rng(seed)
+    mant = rng.uniform(1.0, 2.0, n)
+    x = (rng.choice([-1.0, 1.0], n) * mant * 2.0 ** rng.integers(-100, 127, n)).astype(np.float32)
+    big = np.finfo(np.float32).max
+    edges = [0.0, -0.0, 1.0, -1.0, 2.0**-100, -(2.0**-100), big, -big,
+             np.nextafter(np.float32(1), np.float32(2))]
+    x[: len(edges)] = np.asarray(edges, np.float32)
+    return x
+
+
+def test_pieces_sum_back_to_x_exactly():
+    """hi + mid + lo, summed in f32 in that order, is x bit for bit; a zero
+    comes back as a zero (a float32 sum of zeros is +0 unless all are -0,
+    and a matmul's sum starts from +0 anyway)."""
+    x = _wide_f32(200_000, 0)
+    hi, mid, lo = jax.jit(blocked.pieces)(jnp.asarray(x))
+    assert hi.dtype == mid.dtype == lo.dtype == jnp.bfloat16
+    f32 = lambda a: np.asarray(a.astype(jnp.float32))
+    back = (f32(hi) + f32(mid)) + f32(lo)
+    nz = x != 0
+    np.testing.assert_array_equal(back[nz].view(np.uint32), x[nz].view(np.uint32))
+    assert np.all(back[~nz] == 0)
+    (only,) = blocked.pieces(hi)  # a bf16 array is its own single piece
+    assert only is hi
+
+
+def test_gather_and_rows_are_exact_in_interpret_mode():
+    """The one-hot gather returns `tile[loc]` bit for bit (three bf16 passes
+    lose nothing of an f32 operand), and `_rows` turns a lane vector into
+    columns bit for bit."""
+    from jax.experimental import pallas as pl
+
+    tile_n, blk, w = 64, 128, 128
+    tile = _wide_f32(tile_n * w, 1).reshape(tile_n, w)
+    tile[tile == 0] = 1.5  # a gathered -0 comes back +0: a matmul sums from +0
+    loc = np.random.default_rng(2).integers(0, tile_n, (1, blk)).astype(np.int32)
+    v = _wide_f32(blk, 3)[None, :]
+    v[v == 0] = -2.5
+
+    def kernel(loc_ref, tile_ref, v_ref, rows_ref, col_ref):
+        rows_ref[...] = blocked._gather(loc_ref[...], tile_ref[...])
+        col_ref[...] = blocked._rows(v_ref[...], w)
+
+    rows, col = pl.pallas_call(
+        kernel,
+        out_shape=(jax.ShapeDtypeStruct((blk, w), jnp.float32),
+                   jax.ShapeDtypeStruct((blk, w), jnp.float32)),
+        interpret=True,
+    )(jnp.asarray(loc), jnp.asarray(tile), jnp.asarray(v))
+    np.testing.assert_array_equal(np.asarray(rows).view(np.uint32), tile[loc[0]].view(np.uint32))
+    np.testing.assert_array_equal(
+        np.asarray(col).view(np.uint32), np.broadcast_to(v.T, (blk, w)).view(np.uint32))
+
+
+def _plan_case(kind, st_t, bf16_round=False):
+    """(kernel output, plan-reference output) of one kernel on `st_t`, true
+    columns only; with `bf16_round` the kernel's factors are first rounded
+    to bf16 (the reference keeps them in f32)."""
+    cfg = MemoryControllerConfig(
+        cache=CacheEngineConfig(tile_i=32, tile_j=32, tile_k=32), dma=DMAEngineConfig(blk=64))
+    mode = 1
+    if kind == "mttkrp":
+        op = make_planned_mttkrp(st_t, mode, 16, cfg=cfg)
+        widths, cols = (16, 16), 16
+    elif kind == "ttmc":
+        op = make_planned_ttmc(st_t, mode, (8, 8, 8), cfg=cfg)
+        widths, cols = op.in_ranks, op.out_cols
+    else:
+        op = make_planned_ttcore(st_t, mode, (4, 3), cfg=cfg)
+        widths, cols = tuple(a * b for a, b in op.in_rank_pairs), op.out_cols
+    p = op.plan
+    keys = jax.random.split(jax.random.PRNGKey(7), p.n_in)
+    pads = tuple(
+        pad_factor(jax.random.normal(k, (st_t.shape[m], wd)) / np.sqrt(wd), rows, rank_padded(wd))
+        for k, m, rows, wd in zip(keys, p.in_modes, p.in_rows, widths)
+    )
+    if kind == "mttkrp":
+        ref = mttkrp_plan_ref(p, pads, rank_padded(16))
+        run = lambda f: mttkrp_pallas_call(*op.layout, f, tile_i=p.tile_i, in_tiles=p.in_tiles,
+                                           out_rows=p.out_rows)
+    elif kind == "ttmc":
+        ref = ttmc_plan_ref(p, pads, op.in_ranks)
+        run = op.call_padded
+    else:
+        ref = ttcore_plan_ref(p, pads, op.in_rank_pairs, op.n_left)
+        run = op.call_padded
+    if bf16_round:
+        pads = tuple(f.astype(jnp.bfloat16).astype(jnp.float32) for f in pads)
+    return np.asarray(run(pads))[:, :cols], np.asarray(ref)[:, :cols]
+
+
+@pytest.mark.parametrize("kind", ["mttkrp", "ttmc", "ttcore"])
+def test_kernel_matches_plan_ref_to_float32(tiny_tensor, kind):
+    """Each kernel reproduces its layout-level oracle to float32 rounding
+    (1e-5), which factors rounded to bf16 miss by far: the three-pass
+    matmuls keep every bit of the f32 operands."""
+    out, ref = _plan_case(kind, tiny_tensor)
+    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+    rounded, _ = _plan_case(kind, tiny_tensor, bf16_round=True)
+    assert np.max(np.abs(rounded - ref) / (1e-5 + 1e-5 * np.abs(ref))) > 100
 
 
 @pytest.mark.parametrize("preset", ["4d_small", "5d_small"])

@@ -262,3 +262,17 @@ def test_workspace_records_fetch_bytes_once(small_tensor):
     assert tk.fetch_bytes[0] == kernel_fetch_bytes(p, (256, 128), 2688, tk.ops[0].cfg.remapper,
                                                    chunk_blocks(3))
     metrics.reset()
+
+
+def test_workspace_records_grid_steps_once(small_tensor):
+    """Each single-device workspace records its kernels' grid steps at
+    build as `kernel.grid_steps{mode=}`: each mode's plan's blocks."""
+    from repro.kernels.ops import make_planned_cp_als
+    from repro.obs import metrics
+
+    metrics.reset()
+    ws = make_planned_cp_als(small_tensor, 16)
+    gauges = metrics.snapshot()["gauges"]
+    assert {k: v for k, v in gauges.items() if k.startswith("kernel.grid_steps")} == {
+        f"kernel.grid_steps{{mode={m}}}": float(op.plan.nblocks) for m, op in ws.ops.items()}
+    metrics.reset()

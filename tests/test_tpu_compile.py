@@ -150,3 +150,74 @@ def test_kernel_body_carries_no_checkout_path(one_chip, monkeypatch):
         jax.config.update("jax_hlo_source_file_canonicalization_regex", prev[1])
     assert b"src/repro/kernels/blocked.py" in body
     assert str(root).encode() not in body
+
+
+def _mosaic_text(body: bytes) -> str:
+    """A serialized Mosaic kernel body decoded back to MLIR text."""
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jaxlib.mlir import ir
+    from jaxlib.mlir.passmanager import PassManager
+
+    ctx = mlir.make_ir_context()
+    tpu.register_dialect(ctx)
+    with ctx, ir.Location.unknown():
+        ctx.allow_unregistered_dialects = True  # the serialized, versioned dialect
+        module = ir.Module.parse(body)
+        PassManager.parse("builtin.module(mosaic-serde{serialize=false})").run(module.operation)
+        return str(module)
+
+
+# The benchmark's kernels at its tiles (256) and block (256): nell-2 CP at
+# rank 16, Tucker at ranks (8, 8, 8), TT at ranks (8, 8) for its middle mode.
+_BENCH_KERNELS = {
+    "mttkrp": (mttkrp_pallas_call, dict(out_rows=4096)),
+    "ttmc": (ttmc_pallas_call, dict(in_ranks=(8, 8), out_rows=4096)),
+    "ttcore": (ttcore_pallas_call, dict(in_rank_pairs=((1, 8), (8, 1)), n_left=1,
+                                        out_rows=4096)),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(_BENCH_KERNELS))
+def test_kernel_matmuls_are_single_pass_bf16(one_chip, kind):
+    """Every matmul in the kernel body is one bf16 MXU pass: no operand is
+    f32 and none asks for the six-pass float32 contraction, also when traced
+    under the drivers' float32 default matmul precision (`f32_matmuls`)."""
+    fn, static = _BENCH_KERNELS[kind]
+    args = _args(one_chip, 512, 256, 256, (256, 256), (128, 128))
+    with jax.default_matmul_precision("highest"):
+        body = _kernel_body(fn, args, tile_i=256, in_tiles=(256, 256), **static)
+    text = _mosaic_text(body)
+    matmuls = [line for line in text.splitlines() if "tpu.matmul" in line]
+    assert matmuls
+    assert "contract_precision" not in text
+    for line in matmuls:
+        lhs, rhs = re.search(r":\s*vector<([^>]*)>,\s*vector<([^>]*)>", line).groups()
+        assert lhs.endswith("xbf16") and rhs.endswith("xbf16"), line
+
+
+@pytest.mark.parametrize("fmt", ["cp", "tucker", "tt"])
+def test_sweep_compiles_under_driver_precision(one_chip, fmt, monkeypatch, small_tensor):
+    """Each format's whole sweep, its operands given as shapes on the
+    described chip, compiles for the v5e under the float32 default matmul
+    precision the drivers trace with (`f32_matmuls`): the kernels' bf16
+    matmuls keep their own single-pass precision inside it."""
+    from repro.kernels.ops import make_planned_cp_als
+    from repro.tt.als import make_planned_tt
+    from repro.tucker.hooi import make_planned_tucker
+
+    norm = jnp.float32(1.0)
+    stream = (jnp.asarray(small_tensor.indices), jnp.asarray(small_tensor.values), norm)
+    ws, args = {
+        "cp": lambda: (make_planned_cp_als(small_tensor, 16), stream),
+        "tucker": lambda: (make_planned_tucker(small_tensor, (8, 8, 8)), (norm,)),
+        "tt": lambda: (make_planned_tt(small_tensor, (8, 8)), stream),
+    }[fmt]()
+    facs = tuple(jnp.zeros(s, jnp.float32) for s in zip(ws.padded_rows, ws.rank_pads))
+    shapes = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+                          ws._sweep_operands(facs, args))
+    monkeypatch.setattr(blocked, "interpret_mode", lambda: False)
+    with jax.default_matmul_precision("highest"):
+        for kwargs in ws._sweep_variants():
+            text = ws._jitted_sweep().lower(*shapes, **kwargs).compile().as_text()
+            assert "tpu_custom_call" in text
