@@ -57,12 +57,13 @@ def main() -> None:
     traffic = run.load_json(HERE / "traffic" / f"{cell['traffic']}.json")
     fmt, iters = traffic["format"], int(traffic["iters_per_job"])
     rank = traffic["rank"] if isinstance(traffic["rank"], int) else tuple(traffic["rank"])
-    job = dict(format=fmt, method="pallas", tol=traffic["tol"], **traffic.get("options", {}))
+    method, place = run.placement(traffic, cell["chips"])
+    job = dict(format=fmt, method=method, tol=traffic["tol"], **place, **traffic.get("options", {}))
     for seed in a.seeds:
         t0 = time.perf_counter()
         idx, vals, shape = tensors.generate(config, seed)
         st = SparseTensor(idx, vals, shape)
-        ws = run.load_callable(traffic["workspace"])(st, rank)
+        ws = run.load_callable(traffic["workspace"])(st, rank, **place)
         s = run.job_seed(seed, 0)
         done = decompose(st, rank, planned=ws, seed=s, iters=iters, **job)
         replay = decompose(st, rank, planned=ws, seed=s, iters=iters - 1, **job)

@@ -4,7 +4,7 @@
 exact.  `high()` is the control of the comparison: the same reference in
 float32 on the default device, with every product rounded as
 `Precision.HIGH` (three bf16 passes) rounds it, the step below the float32
-at `Precision.HIGHEST` that the configurations state.  Matmuls take the
+values and accumulation with exact products that the configurations state.  Matmuls take the
 precision from `jax.default_matmul_precision`; elementwise products, which
 no precision setting touches, split each operand into a bf16 head and a
 bf16 tail and drop the tail-by-tail term, as the three-pass MXU product

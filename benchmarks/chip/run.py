@@ -6,10 +6,11 @@
 A cell (`workloads` in BENCHMARK.json) names a configuration
 (`configs/<name>.json`: a tensor's shape, nonzeros and skew) and a traffic
 mix (`traffic/<name>.json`: format, rank, iterations per job, the
-program's workspace builder).  Everything else is found by name: the
-format's compulsory work (`costs/<format>.py`), its plain reference
-(`reference/<format>.py`), the cell's limits (`limits/<workload>.json`)
-and one reader per metric (`metrics/<metric>.py`).
+program's workspace builder and its method, `"pallas"` where it names
+none; a `"pallas_sharded"` workspace spans the cell's chips).  Everything
+else is found by name: the format's compulsory work (`costs/<format>.py`),
+its plain reference (`reference/<format>.py`), the cell's limits
+(`limits/<workload>.json`) and one reader per metric (`metrics/<metric>.py`).
 
 Set-up generates the tensor from --seed (`tensors.py`), builds the
 program's workspace (one plan per mode, layouts to the device) and runs a
@@ -121,6 +122,29 @@ class CompileCounter:
             self.calls += 1
 
 
+def placement(traffic: dict, chips: int) -> tuple[str, dict]:
+    """The program's method (`"pallas"` where the traffic names none) and
+    where it runs: the sharded path over the cell's `chips` devices, as the
+    workspace builder and every `decompose` call are told."""
+    method = traffic.get("method", "pallas")
+    return method, ({"devices": chips} if method == "pallas_sharded" else {})
+
+
+def layouts(ws) -> tuple[list, int, int]:
+    """(the layouts' device arrays, the slots the kernels walk, the true
+    nonzeros), over every mode of a workspace.  A sharded mode's stack runs
+    each of its D shards for the widest shard's NB blocks of blk slots, so
+    its slots are D x NB x blk and shard imbalance counts as padding."""
+    stacks = getattr(ws, "stacks", None)
+    if stacks is not None:
+        return ([s.tree() for s in stacks.values()],
+                sum(s.nshards * s.nblocks * s.blk for s in stacks.values()),
+                sum(sum(s.shard_nnz) for s in stacks.values()))
+    plans = [op.plan for op in ws.ops.values()]
+    return ([op.layout for op in ws.ops.values()],
+            sum(p.nblocks * p.blk for p in plans), sum(p.nnz for p in plans))
+
+
 def device_record(devices, chips: int) -> dict:
     used = devices[:chips]
     peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in used]
@@ -161,16 +185,21 @@ def run(bench: dict, workload: str, seed: int, seconds: float, trace: bool,
 
     idx, vals, shape = tensors.generate(config, seed)
     st = SparseTensor(idx, vals, shape)
-    job = dict(format=fmt, method="pallas", iters=iters, tol=traffic["tol"],
+    chips = cell["chips"]
+    method, place = placement(traffic, chips)
+    job = dict(format=fmt, method=method, iters=iters, tol=traffic["tol"], **place,
                **traffic.get("options", {}))
 
     t0 = time.perf_counter()
-    ws = load_callable(traffic["workspace"])(st, rank)
-    jax.block_until_ready([op.layout for op in ws.ops.values()])
+    ws = load_callable(traffic["workspace"])(st, rank, **place)
+    arrays, slots, nnz = layouts(ws)
+    jax.block_until_ready(arrays)
     plan_build_s = time.perf_counter() - t0
-    plans = [op.plan for op in ws.ops.values()]
-    slots = sum(p.nblocks * p.blk for p in plans)
-    padded = slots - sum(p.nnz for p in plans)
+    padded = slots - nnz
+    spans = getattr(ws, "nshards", 1)
+    if spans != chips:
+        raise ValueError(f"{workload}: the workspace spans {spans} device(s); "
+                         f"the cell asks for {chips}")
 
     check.state_arrays(decompose(st, rank, planned=ws, seed=job_seed(seed, 2**32),
                                  **{**job, "iters": WARMUP_ITERS}))
@@ -208,9 +237,10 @@ def run(bench: dict, workload: str, seed: int, seconds: float, trace: bool,
         program_trace.disable()
         jax.profiler.stop_trace()
         with jax.profiler.TraceAnnotation("bench.reduce"):
-            reduction = trace_reduce.reduce(trace_reduce.load(trace_reduce.find_xplane(trace_dir.name)))
+            reduction = trace_reduce.reduce(
+                trace_reduce.load(trace_reduce.find_xplane(trace_dir.name)), chips=chips)
         trace_dir.cleanup()
-    device = device_record(devices, cell["chips"])
+    device = device_record(devices, chips)
 
     # The check: every job's fits; then one job, drawn from the seed, in full.
     attempted = len(results)
@@ -240,11 +270,12 @@ def run(bench: dict, workload: str, seed: int, seconds: float, trace: bool,
     if peak is not None:
         per_mode = [(w["bytes"] / peak["hbm_bytes_per_s"], w["flops"] / peak["flops_per_s"])
                     for w in work]
-        bound_s = sum(max(b, f) for b, f in per_mode)
+        # The whole tensor's work against the peaks of all the cell's chips.
+        bound_s = sum(max(b, f) for b, f in per_mode) / chips
         log(f"[bench] compulsory kernel work per iteration "
             f"{sum(w['bytes'] for w in work):,} B and {sum(w['flops'] for w in work):,} op: "
             f"{'bytes bind' if all(b >= f for b, f in per_mode) else 'operations bind'} "
-            f"the roofline at {bound_s * 1e6:.3f} us")
+            f"the roofline at {bound_s * 1e6:.3f} us on {chips} chip(s)")
     ctx = types.SimpleNamespace(
         window_s=window_s, iterations=iterations, setup_s=setup_s,
         hbm_peak_bytes=device["memory_peak_bytes"], plan_build_s=plan_build_s,
@@ -270,8 +301,9 @@ def run(bench: dict, workload: str, seed: int, seconds: float, trace: bool,
         device["window_s"] = reduction.window_s
         result["breakdown"] = {"device_ops": reduction.device_ops,
                                "idle_gaps": reduction.idle_gaps}
-        log(f"[bench] trace: window {reduction.window_s:.6f} s, busy {reduction.busy_s:.6f} s, "
-            f"kernel {reduction.kernel_s:.6f} s in {reduction.kernel_events} events")
+        log(f"[bench] trace, per chip: window {reduction.window_s:.6f} s, busy "
+            f"{reduction.busy_s:.6f} s, kernel {reduction.kernel_s:.6f} s in "
+            f"{reduction.kernel_events} events, collectives {reduction.collective_s:.6f} s")
     result["checks"] = checks
     return result
 

@@ -11,9 +11,14 @@ values and the order in which the nonzeros reach the program.
 """
 from __future__ import annotations
 
+import math
+
 import numpy as np
 
 __all__ = ["coordinates", "generate"]
+
+INDEX_MAX = int(np.iinfo(np.int64).max)
+EXACT_MAX = 2**53  # float64 holds every integer up to here exactly
 
 
 def _zipf_sampler(rng: np.random.Generator, size: int, alpha: float):
@@ -27,24 +32,53 @@ def _zipf_sampler(rng: np.random.Generator, size: int, alpha: float):
     return lambda n: labels[rng.choice(size, size=n, p=probs)]
 
 
+def _mode_runs(shape: tuple[int, ...]) -> list[tuple[int, int]]:
+    """The modes as runs [a, b) whose mixed-radix indices key a cell: one run
+    where the whole tensor's linear index fits int64; else a leading and a
+    trailing run, each with at most 2**53 cells, so that a pair of keys
+    sorts exactly as a complex128 (`_distinct`)."""
+    if math.prod(shape) <= INDEX_MAX:
+        return [(0, len(shape))]
+    cut = max(m for m in range(1, len(shape)) if math.prod(shape[:m]) <= EXACT_MAX)
+    if math.prod(shape[cut:]) > EXACT_MAX:
+        raise ValueError(f"a {shape} tensor's cells need more than two keys")
+    return [(0, cut), (cut, len(shape))]
+
+
+def _distinct(keys: list[np.ndarray]) -> list[np.ndarray]:
+    """The distinct rows of one or two key columns, in lexicographic order.
+    Two keys below 2**53 are exact in float64, and numpy sorts complex
+    numbers by real part, then imaginary part."""
+    if len(keys) == 1:
+        return [np.unique(keys[0])]
+    pairs = np.unique(keys[0].astype(np.float64) + 1j * keys[1].astype(np.float64))
+    return [pairs.real.astype(np.int64), pairs.imag.astype(np.int64)]
+
+
 def coordinates(shape, nnz: int, skew, structure_seed: int) -> np.ndarray:
     """`nnz` distinct coordinates, (nnz, nmodes) int64, in ascending
-    linear order.  Draws in rounds until enough distinct cells are hit,
-    then keeps a random `nnz` of them."""
+    lexicographic (C-order linear) order.  Draws in rounds until enough
+    distinct cells are hit, then keeps a random `nnz` of them.  A cell is
+    keyed by the mixed-radix index of each run of modes (`_mode_runs`),
+    so no key overflows where the tensor's linear index would."""
     shape = tuple(int(s) for s in shape)
-    total = int(np.prod(shape, dtype=np.float64))
+    total = math.prod(shape)
     if not 0 < nnz <= total // 2:
         raise ValueError(f"nnz {nnz} does not fit a {shape} tensor as a sparse set")
     rng = np.random.default_rng(structure_seed)
     draw = [_zipf_sampler(rng, s, a) for s, a in zip(shape, skew)]
-    cells = np.empty((0,), np.int64)
-    while cells.size < nnz:
-        n = max(1024, int(1.5 * (nnz - cells.size)))
-        lin = np.ravel_multi_index(tuple(d(n) for d in draw), shape)
-        cells = np.union1d(cells, lin)
-    if cells.size > nnz:
-        cells = np.sort(rng.choice(cells, size=nnz, replace=False))
-    return np.stack(np.unravel_index(cells, shape), axis=1)
+    runs = _mode_runs(shape)
+    cells = [np.empty((0,), np.int64) for _ in runs]
+    while cells[0].size < nnz:
+        n = max(1024, int(1.5 * (nnz - cells[0].size)))
+        coords = [d(n) for d in draw]
+        keys = [np.ravel_multi_index(tuple(coords[a:b]), shape[a:b]) for a, b in runs]
+        cells = _distinct([np.concatenate([c, k]) for c, k in zip(cells, keys)])
+    if cells[0].size > nnz:
+        keep = np.sort(rng.choice(cells[0].size, size=nnz, replace=False))
+        cells = [c[keep] for c in cells]
+    return np.concatenate([np.stack(np.unravel_index(c, shape[a:b]), axis=1)
+                           for c, (a, b) in zip(cells, runs)], axis=1)
 
 
 def generate(config: dict, seed: int) -> tuple[np.ndarray, np.ndarray, tuple[int, ...]]:

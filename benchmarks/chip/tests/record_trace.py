@@ -83,7 +83,7 @@ def main() -> None:
         mark = (b"<checkout" + b"-" * len(root))[: len(root) - 2] + b">/"
         (out / "small.xplane.pb").write_bytes(raw.replace(root, mark))
     (out / "summary.json").write_text(json.dumps(summary(str(out / "small.xplane.pb")), indent=1))
-    red = trace_reduce.reduce(trace_reduce.load(str(out / "small.xplane.pb")))
+    red = trace_reduce.reduce(trace_reduce.load(str(out / "small.xplane.pb")), chips=1)
     print(json.dumps(red.__dict__))
 
 

@@ -3,7 +3,7 @@
     JAX_PLATFORMS=cpu python -m pytest -q benchmarks/chip/tests
 
 The control is the plain reference put in the program's place and computed
-one precision step below the configurations' float32 at Precision.HIGHEST:
+one precision step below the configurations' float32 with exact products:
 `Precision.HIGH`, three bf16 passes per product.  On the chip it is read by
 readings.py at each cell's own size; here, at the tiny sizes under
 tests/data, it must fail one of each cell's numbers while the program's

@@ -79,7 +79,7 @@ def test_split_by_hand():
         "job": (150 - 50 + 400 - 340 + 900 - 850) * 1e-9,
         "outside": (20 + 50 - 40 + 1000 - 900) * 1e-9,
     })
-    red = tr.reduce(events)
+    red = tr.reduce(events, chips=1)
     assert sum(out.idle_s.values()) == pytest.approx(red.window_s - red.busy_s)
 
 
@@ -110,7 +110,7 @@ def test_recorded_scoped_chip_trace():
     assert out.sweep_s * 1e3 == pytest.approx(15.026605, abs=1e-6)
     assert {k: v * 1e3 for k, v in out.idle_s.items()} == pytest.approx(
         {"iteration": 4.350326, "job": 47.054328, "outside": 0.703684}, abs=1e-6)
-    red = tr.reduce(events)
+    red = tr.reduce(events, chips=1)
     assert red.kernel_events == 2 * 2 * 3
     kernels = sum(v for k, v in out.scope_s.items() if k.endswith(".kernel"))
     assert kernels == pytest.approx(red.kernel_s, abs=1e-9)
@@ -129,7 +129,7 @@ def test_idle_phases_on_the_unscoped_chip_trace():
     assert out.scope_s == {} and out.sweep_s == 0.0
     assert out.idle_s == pytest.approx(
         {"iteration": 4.184449e-3, "job": 45.979157e-3, "outside": 0.641826e-3}, abs=1e-9)
-    red = tr.reduce(events)
+    red = tr.reduce(events, chips=1)
     assert sum(out.idle_s.values()) == pytest.approx(red.window_s - red.busy_s, abs=1e-9)
 
 

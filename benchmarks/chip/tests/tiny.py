@@ -1,5 +1,7 @@
 """The harness on the CPU at test sizes: tiny cells over the data under
-tests/data, run through run.run with the chip check skipped."""
+tests/data, run through run.run with the chip check skipped.  `CELLS` run
+on one device; `SHARDED` on four, which a process has only where
+XLA_FLAGS forces four host devices (sharded_cell.py)."""
 import json
 import sys
 import time
@@ -19,12 +21,14 @@ CELLS = {
     "tiny.tt": ("tiny", "tt-r3"),
     "tiny.tucker": ("tiny", "tucker-r3"),
 }
+SHARDED = {"tiny4.cp.sharded": ("tiny4", "cp-r4-sharded", 4)}
 
 
 def bench() -> dict:
     b = json.loads((CHIP.parent.parent / "BENCHMARK.json").read_text())
-    b["workloads"] = [{"name": n, "config": c, "traffic": t, "chips": 1, "why": "test"}
-                      for n, (c, t) in CELLS.items()]
+    cells = {**{n: (c, t, 1) for n, (c, t) in CELLS.items()}, **SHARDED}
+    b["workloads"] = [{"name": n, "config": c, "traffic": t, "chips": chips, "why": "test"}
+                      for n, (c, t, chips) in cells.items()]
     return b
 
 

@@ -7,16 +7,23 @@ every number the per-layer metrics read:
     annotation on the host;
   * device busy time: the union of the intervals in which an operation
     runs on a device (the `XLA Ops` line of each `/device:TPU:n` plane),
-    clipped to the window, averaged over the devices that ran anything;
-  * kernel time: the summed device durations of the Pallas kernels' events.
+    clipped to the window, averaged over the cell's chips, so that a chip
+    that ran nothing counts as idle;
+  * kernel time: the summed device durations of the Pallas kernels' events,
+    per chip.
     The program gives its `pallas_call`s no name, so a kernel is found by
     what the trace prints for it: an XLA op whose own HLO instruction is a
     custom call to `KERNEL_TARGET`.  The ops of the jitted wrappers around
     a kernel (reshapes, pads, the fusions that read its output) are not
     kernel time, even where their names mention the wrapper;
+  * collective time: the summed device durations, per chip, of the
+    collective ops: an op whose own HLO instruction's opcode is one of
+    `COLLECTIVES` (each also as its async `-start` and `-done` halves).
+    The opcode, not the name: JAX names a psum's all-reduce `psum.N`;
   * `breakdown`: the device operations that took the most time, under the
     names the trace prints (an XLA op's HLO name, its `.N` suffix dropped so
-    that the chunks of one kernel add up), and the longest idle gaps, each
+    that the chunks of one kernel add up), per chip, and the longest idle
+    gaps of any chip (one that ran nothing is idle the whole window), each
     named by the innermost event of the host thread that ran the window
     (the benchmark's `bench.*` annotations, the program's spans, JAX's own
     dispatch events) that covers the gap's midpoint.
@@ -36,6 +43,13 @@ WINDOW = "bench.window"
 OPS_LINE = "XLA Ops"
 KERNEL_TARGET = "tpu_custom_call"
 TOP = 10
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
+               "collective-permute")
+_COLLECTIVE = "(%s)(-start|-done)?" % "|".join(map(re.escape, COLLECTIVES))
+# The opcode follows the instruction's shape after a space and opens its operand
+# list; an operand or a called computation is named with a leading `%`.
+_COLLECTIVE_OPCODE_RE = re.compile(r" = .*?(?<= )%s\(" % _COLLECTIVE)
+_COLLECTIVE_NAME_RE = re.compile(r"^%s$" % _COLLECTIVE)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -58,9 +72,10 @@ class Reduction:
     busy_s: float
     kernel_s: float
     kernel_events: int
-    devices: int
-    device_ops: list  # [[name, seconds], ...] most time first
+    devices: int  # devices that ran anything in the window
+    device_ops: list  # [[name, seconds per chip], ...] most time first
     idle_gaps: list  # [[host span, seconds], ...] longest first
+    collective_s: float  # per chip
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -120,7 +135,17 @@ def _is_kernel(ev: Event) -> bool:
     return f'custom_call_target="{KERNEL_TARGET}"' in f"{ev.name} {ev.text}"
 
 
-def reduce(events: list[Event], window: str = WINDOW) -> Reduction:
+def is_collective(ev: Event) -> bool:
+    """The op's HLO instruction (`%psum.3 = f32[8]{0} all-reduce(...)`),
+    printed as its name or in its stats, is a collective; so is an op the
+    trace names by a bare collective opcode (`all-reduce.1`)."""
+    return bool(_COLLECTIVE_OPCODE_RE.search(f"{ev.name} {ev.text}")
+                or _COLLECTIVE_NAME_RE.match(op_name(ev.name)))
+
+
+def reduce(events: list[Event], *, chips: int, window: str = WINDOW) -> Reduction:
+    """The window's device numbers, per chip of the `chips` the cell runs on
+    (more, should more devices have run anything)."""
     host = [e for e in events if e.plane.startswith("/host:")]
     marks = [e for e in host if e.name == window]
     if not marks:
@@ -131,22 +156,27 @@ def reduce(events: list[Event], window: str = WINDOW) -> Reduction:
     per_device: dict[str, list] = {}
     for e in ops:
         per_device.setdefault(e.plane, []).append((max(e.start_ns, w0), min(e.end_ns, w1)))
-    devices = max(1, len(per_device))
+    devices = max(chips, len(per_device))
+
+    def per_chip(evs) -> float:
+        return sum(min(e.end_ns, w1) - max(e.start_ns, w0) for e in evs) / devices
+
     busy = sum(_union_ns(iv) for iv in per_device.values()) / devices
     kernels = [e for e in ops if _is_kernel(e)]
-    kernel = sum(min(e.end_ns, w1) - max(e.start_ns, w0) for e in kernels) / devices
+    kernel = per_chip(kernels)
+    collective = per_chip(e for e in ops if is_collective(e))
 
-    by_name: dict[str, float] = {}
+    by_name: dict[str, list] = {}
     for e in ops:
-        key = op_name(e.name)
-        by_name[key] = by_name.get(key, 0.0) + (min(e.end_ns, w1) - max(e.start_ns, w0))
-    device_ops = sorted(by_name.items(), key=lambda kv: -kv[1])[:TOP]
+        by_name.setdefault(op_name(e.name), []).append(e)
+    device_ops = sorted(((n, per_chip(evs)) for n, evs in by_name.items()),
+                        key=lambda kv: -kv[1])[:TOP]
 
-    # Idle gaps on the first device that ran anything, named by the host span.
-    gaps = []
-    if per_device:
+    # Idle gaps of every chip, named by the host span.
+    gaps = [(w0, w1)] * (devices - len(per_device))
+    for intervals in per_device.values():
         end = w0
-        for s, e in sorted(next(iter(per_device.values()))):
+        for s, e in sorted(intervals):
             if s > end:
                 gaps.append((end, s))
             end = max(end, e)
@@ -164,7 +194,8 @@ def reduce(events: list[Event], window: str = WINDOW) -> Reduction:
         busy_s=busy * 1e-9,
         kernel_s=kernel * 1e-9,
         kernel_events=len(kernels),
-        devices=devices,
+        devices=len(per_device),
         device_ops=[[n, t * 1e-9] for n, t in device_ops],
         idle_gaps=named,
+        collective_s=collective * 1e-9,
     )
